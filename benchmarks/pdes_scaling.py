@@ -1,6 +1,7 @@
 """§Perf cell C: the sharded conservative-PDES engine itself.
 
-Measures (single-device fallback if only 1 device visible):
+Measures, on every visible device (at least two — with one it fails
+rather than report a one-shard number as a sharded one):
   * wall time per simulated virtual cycle vs the conservative lookahead
     window (the classic PDES sync/skew trade-off: larger windows = fewer
     pmin barriers + fewer mailbox exchanges, at the cost of later message
@@ -9,7 +10,7 @@ Measures (single-device fallback if only 1 device visible):
   * cross-shard collective bytes per simulated cycle from the lowered
     512-chip artifact (the dry-run's own metric).
 
-Run with multiple fake devices for the real measurement:
+Run it on a multi-chip host, or on the CPU with forced host devices:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python -m benchmarks.run --only pdes_scaling
 """
@@ -25,6 +26,10 @@ def bench():
     from repro.sims.memsys import build_sharded_memsys
 
     n = len(jax.devices())
+    if n < 2:
+        raise RuntimeError(
+            f"pdes_scaling needs 2+ devices, found {n} "
+            f"{jax.devices()[0].platform} device")
     mesh = make_sim_mesh(n)
     rows = []
     horizon = 2000.0

@@ -13,18 +13,42 @@
   (dse)      search_convergence   successive-halving search vs exhaustive
                                   sweep: objective gap + cycle budget
                                   (DSE.md "Search")
-  (dse)      sharded_sweep        2-device sharded rounds vs the monolithic
-                                  pmap round (DSE.md "Sharded sweeps")
+  (dse)      sharded_sweep        sharded rounds vs the monolithic pmap
+                                  round on >= 2 devices (DSE.md "Sharded
+                                  sweeps")
 
-Prints ``name,us_per_call,derived`` CSV.  Roofline terms for the assigned
-architectures come from the dry-run (see launch/dryrun.py + EXPERIMENTS.md);
-they are analysis artifacts, not wall-time benchmarks.
+Prints ``name,us_per_call,derived`` CSV.  The harness itself never
+touches JAX: each module runs in a fresh child process of its own, so a
+module that needs the accelerator (or starts processes of its own, as
+``struct_sweep`` does before it touches JAX) never finds it held by a
+parent.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
+import subprocess
 import sys
+
+MODULES = ("smart_ticking", "parallel_sim", "tracing_overhead", "onira_cpi",
+           "triosim_validation", "kernels", "pdes_scaling", "dse_throughput",
+           "struct_sweep", "search_convergence", "sharded_sweep")
+
+# the child: import one module, run it, print its rows as the last line
+_CHILD = ("import json, sys; from benchmarks import {0} as m; "
+          "print(json.dumps(m.bench(), default=float))")
+
+
+def _bench(name: str) -> list[dict]:
+    """Run ``benchmarks.<name>.bench()`` in a child process (stderr passes
+    through) and return its rows; raises on a non-zero exit."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", _CHILD.format(name)],
+                       cwd=root, stdout=subprocess.PIPE, text=True)
+    if r.returncode != 0:
+        raise RuntimeError(f"exit code {r.returncode}")
+    return json.loads(r.stdout.strip().splitlines()[-1])
 
 
 def main() -> None:
@@ -36,33 +60,14 @@ def main() -> None:
                          "derived metrics) as JSON, e.g. BENCH_engine.json, "
                          "so future PRs have a perf trajectory to compare")
     args = ap.parse_args()
-
-    from . import (dse_throughput, kernels, onira_cpi, parallel_sim,
-                   pdes_scaling, search_convergence, sharded_sweep,
-                   smart_ticking, struct_sweep, tracing_overhead,
-                   triosim_validation)
-    modules = {
-        "smart_ticking": smart_ticking,
-        "parallel_sim": parallel_sim,
-        "tracing_overhead": tracing_overhead,
-        "onira_cpi": onira_cpi,
-        "triosim_validation": triosim_validation,
-        "kernels": kernels,
-        "pdes_scaling": pdes_scaling,
-        "dse_throughput": dse_throughput,
-        "struct_sweep": struct_sweep,
-        "search_convergence": search_convergence,
-        "sharded_sweep": sharded_sweep,
-    }
-    if args.only:
-        modules = {k: v for k, v in modules.items() if k in args.only}
+    modules = [m for m in MODULES if not args.only or m in args.only]
 
     print("name,us_per_call,derived")
     failures = 0
     results: dict[str, dict] = {}
-    for name, mod in modules.items():
+    for name in modules:
         try:
-            for row in mod.bench():
+            for row in _bench(name):
                 print(f"{row['name']},{row['us_per_call']:.1f},"
                       f"\"{row['derived']}\"")
                 sys.stdout.flush()

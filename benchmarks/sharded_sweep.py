@@ -1,13 +1,15 @@
-"""Sharded sweep rounds over a 2-device mesh versus the monolithic pmap
-round it replaces (DSE.md "Sharded sweeps and the persistent cache").
+"""Sharded sweep rounds over the local device mesh versus the monolithic
+pmap round it replaces (DSE.md "Sharded sweeps and the persistent cache").
 
-The multi-device path is only reachable with >1 device, so the whole
-measurement runs in one subprocess with two forced host devices (the
-same trick ``tests/dse/test_sharded.py`` uses).  Inside it, on the
+The multi-device path needs at least two devices, and this module runs
+in the process that holds them (one process per chip): on a multi-chip
+host it spans the chips; on the CPU run it with forced host devices,
+``XLA_FLAGS=--xla_force_host_platform_device_count=2``.  With fewer than
+two devices it fails instead of measuring something else.  On the
 straggler-heavy workload of ``dse_throughput`` (B=256, per-lane horizons
 spread ~8x):
 
-* ``pmap_monolith`` — lanes laid out ``[2, 128]``, ONE ``jax.pmap`` of
+* ``pmap_monolith`` — lanes laid out ``[D, 256/D]``, ONE ``jax.pmap`` of
   the vmapped run to every lane's own horizon: the pre-rounds sharding
   story.  Each device iterates until its *slowest* local lane is done,
   finished lanes burn masked epochs, and a drained device idles while
@@ -16,26 +18,27 @@ spread ~8x):
   executable per ladder rung across the whole mesh, with the global
   harvest/compact/refill re-packing survivors across shards each round.
 
-Both paths compute bit-identical rows (asserted in the worker); the CI
-bar gates the speedup at >= 1.5x.
+Both paths compute bit-identical rows (asserted here); the CI bar gates
+the speedup at >= 1.5x.
 """
-import json
-import os
-import subprocess
-import sys
-import textwrap
+import time
 
-_WORKER = textwrap.dedent("""
-    import json, time
+B = 256
+SPREAD = 8       # horizons span [drain/8, ~drain]
+REPS = 2
+
+
+def _measure():
     import jax
     import numpy as np
-    assert jax.local_device_count() == 2, jax.local_device_count()
     from repro.dse import BatchRunner, build_param_batch, stack_states
     from repro.sims.memsys import build
 
-    B, D = 256, 2
-    SPREAD = 8       # horizons span [drain/8, ~drain]
-    REPS = 2
+    D = jax.local_device_count()
+    if D < 2 or B % D:
+        raise RuntimeError(
+            f"sharded_sweep needs 2+ devices dividing {B}, found {D} "
+            f"{jax.devices()[0].platform} device(s)")
 
     sim, st = build(n_cores=8, pattern="mixed", n_reqs=256, donate=True)
     pts = [{"conn_latency[-1]": 10.0 + (30.0 * i) / (B - 1),
@@ -47,9 +50,9 @@ _WORKER = textwrap.dedent("""
     # lanes stop in the low-horizon band, 10% climb to the workload's
     # drain time (the classic DSE shape: most configs answer quickly, a
     # few pathological ones grind).  An i*11 stride decorrelates the
-    # stragglers from the param axes AND from the [2, 128] shard
-    # boundary, so the pmap baseline is not rigged: both devices get
-    # their fair share of long lanes.
+    # stragglers from the param axes AND from the [D, B/D] shard
+    # boundary, so the pmap baseline is not rigged: every device gets
+    # its fair share of long lanes.
     r0 = BatchRunner(sim)
     probe = r0.run_batch(stack_states(st, 1),
                          jax.tree.map(lambda x: x[:1], pb), 1e9)
@@ -60,7 +63,7 @@ _WORKER = textwrap.dedent("""
                  lo + (top - lo) * (frac - 0.9) / 0.1).astype(np.float32)
     m = np.full(B, 2_000_000, np.int32)
 
-    # ---- baseline: one pmapped round, [2, 128] lanes, full horizons
+    # ---- baseline: one pmapped round, [D, B/D] lanes, full horizons
     def one(s, p, uu, mm):
         return sim._run(s, uu, mm, params=p)
     pm = jax.pmap(jax.vmap(one), donate_argnums=(0,))
@@ -92,35 +95,23 @@ _WORKER = textwrap.dedent("""
         out = runner.run_rounds(st, pb, u, shard=True)
         out.time.block_until_ready()
         dt_rounds = min(dt_rounds, time.perf_counter() - t0)
-
-    print(json.dumps({
-        "dt_base": dt_base, "dt_rounds": dt_rounds, "B": B,
-        "chunk": runner.last_rounds["chunk"],
-        "rounds": runner.last_rounds["rounds"],
-        "shard": runner.last_rounds["shard"]}))
-""")
+    return {"dt_base": dt_base, "dt_rounds": dt_rounds, "D": D,
+            "chunk": runner.last_rounds["chunk"],
+            "rounds": runner.last_rounds["rounds"],
+            "shard": runner.last_rounds["shard"]}
 
 
 def bench():
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        " --xla_force_host_platform_device_count=2").strip()
-    r = subprocess.run([sys.executable, "-c", _WORKER],
-                       capture_output=True, text=True, timeout=1800,
-                       env=env)
-    if r.returncode != 0:
-        raise RuntimeError(f"sharded worker failed: {r.stderr[-3000:]}")
-    d = json.loads(r.stdout.strip().splitlines()[-1])
-    b, base_cps = d["B"], d["B"] / d["dt_base"]
+    d = _measure()
+    b, base_cps = B, B / d["dt_base"]
     cps = b / d["dt_rounds"]
     return [
         {
             "name": "sharded_sweep/pmap_monolith_B256_mixed",
             "us_per_call": d["dt_base"] * 1e6,
             "derived": f"{base_cps:.1f} configs/s (one pmap round, "
-                       f"[2, {b // 2}] lanes, ~8x horizon spread)",
+                       f"[{d['D']}, {b // d['D']}] lanes, ~8x horizon "
+                       f"spread)",
             "configs_per_sec": base_cps,
         },
         {

@@ -24,19 +24,22 @@ and each times the family build + compile + run from post-import.
   deserializes from the persistent compilation cache — **zero** misses,
   and the ≥5x CI bar gates the wall-clock ratio against the uncached
   run.
+
+The two cold-start processes run first, while this process has not yet
+touched JAX (a parent that holds the accelerator would starve them),
+and share one fixed campaign cache dir and one fixed JAX compilation
+cache inside the checkout, emptied beforehand so the first is uncached.
 """
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import textwrap
 import time
 
-import jax
-
-from repro.dse import BatchRunner, stack_params, stack_state_list
-from repro.sims.memsys import build, build_family
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+COLD_CACHE = os.path.join(ROOT, ".repro_cache", "struct_sweep_cold")
 
 SHAPES = (1, 2, 4, 8)
 UNTIL = 50000.0
@@ -74,11 +77,11 @@ _COLD_WORKER = textwrap.dedent("""
 """) % (SHAPES, UNTIL, N_REQS)
 
 
-def _cold_run(cache_dir):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+def _cold_run():
     env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
-    env["REPRO_CACHE_DIR"] = cache_dir
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["REPRO_CACHE_DIR"] = os.path.join(COLD_CACHE, "campaign")
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(COLD_CACHE, "jax")
     r = subprocess.run([sys.executable, "-c", _COLD_WORKER],
                        capture_output=True, text=True, timeout=1800,
                        env=env)
@@ -88,12 +91,23 @@ def _cold_run(cache_dir):
 
 
 def _family_batch(fam):
+    import jax
+    from repro.dse import stack_params, stack_state_list
     pb = stack_params([fam.params_for({"core": s}) for s in SHAPES])
     sb = stack_state_list([fam.state_for({"core": s}) for s in SHAPES])
     return jax.block_until_ready(sb), jax.block_until_ready(pb)
 
 
 def bench():
+    # two-process persistent-cache cold start: same family workload,
+    # fresh interpreter each time, shared campaign cache dir — before
+    # this process touches JAX
+    shutil.rmtree(COLD_CACHE, ignore_errors=True)
+    uncached = _cold_run()
+    cached = _cold_run()
+
+    from repro.dse import BatchRunner
+    from repro.sims.memsys import build, build_family
     rows = []
     n = len(SHAPES)
 
@@ -149,11 +163,6 @@ def bench():
         "speedup_vs_rebuild": warm_cps / base_cps,
     })
 
-    # two-process persistent-cache cold start: same family workload,
-    # fresh interpreter each time, shared campaign cache dir
-    with tempfile.TemporaryDirectory(prefix="repro_cache_") as cdir:
-        uncached = _cold_run(cdir)
-        cached = _cold_run(cdir)
     if cached["rows"] != uncached["rows"]:
         raise RuntimeError(
             f"cached cold start changed rows: {cached['rows']} "

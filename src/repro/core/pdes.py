@@ -30,20 +30,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:                        # jax >= 0.5 exports it at top level
-    shard_map_compat = jax.shard_map
-except AttributeError:      # pragma: no cover
-    from jax.experimental.shard_map import shard_map as shard_map_compat
-
-# replication-check kwarg was renamed check_rep -> check_vma across versions
-import inspect as _inspect
-
-_SM_KW: dict = {}
-for _kw in ("check_vma", "check_rep"):
-    if _kw in _inspect.signature(shard_map_compat).parameters:
-        _SM_KW = {_kw: False}
-        break
-
 from .component import ComponentKind, TickResult
 from .engine import INF, SimBuilder, Simulation, _align_after
 from .message import MSG_WORDS, W_DST, W_TIME, f2i
@@ -236,8 +222,8 @@ class ShardedSim:
         spec = lambda a: P(*([self.axis] + [None] * (a.ndim - 1)))
         in_specs = jax.tree.map(spec, stacked_state)
 
-        @partial(shard_map_compat, mesh=self.mesh, in_specs=(in_specs,),
-                 out_specs=(in_specs, P(self.axis)), **_SM_KW)
+        @partial(jax.shard_map, mesh=self.mesh, in_specs=(in_specs,),
+                 out_specs=(in_specs, P(self.axis)), check_vma=False)
         def _run(st):
             s = jax.tree.map(lambda a: a[0], st)     # local shard
 

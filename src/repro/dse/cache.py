@@ -7,23 +7,29 @@ the memsys family, 0.53 shapes/s cold vs 51.5 warm (BENCH_struct.json)
 — once *per process* unless compiled executables outlive the process.
 This module makes them outlive it, at two layers:
 
-* **XLA executables** — :func:`ensure_enabled` wires
-  ``jax.experimental.compilation_cache`` to an on-disk directory (the
-  ``REPRO_CACHE_DIR`` environment variable or :func:`configure`), with
-  the min-compile-time/min-entry-size thresholds dropped to zero so
-  every sweep executable persists.  ``run_sweep`` calls this on entry
-  ("enable-on-first-sweep"), so any process that runs a sweep with a
-  cache dir configured reads and writes the shared cache; the second
-  process of a campaign deserializes instead of compiling.
+* **XLA executables** — :func:`enable_jax_cache` turns on
+  ``jax.experimental.compilation_cache`` with the min-compile-time/
+  min-entry-size thresholds dropped to zero so every sweep executable
+  persists.  The cache lives where ``JAX_COMPILATION_CACHE_DIR`` says
+  (JAX reads the variable itself, and nothing here sets another
+  directory); without it, at :data:`JAX_CACHE_DIR`, one fixed path
+  inside the checkout — the path is part of what a later process must
+  find again, so it never carries a temporary name, a pid or a time.
+  ``run_sweep`` calls :func:`ensure_enabled` on entry
+  ("enable-on-first-sweep"), which turns it on whenever a campaign dir
+  is configured, so the second process of a campaign deserializes
+  instead of compiling.
 * **Whole AOT executables** — the jax persistent cache skips XLA
   *compilation* but a fresh process still re-traces and re-lowers every
   program, and on the batched while-loop engine trace+lower is seconds
   per rung — the dominant warm-start cost once compiles are cached.
   :func:`get_executable` / :func:`put_executable` persist the runner's
-  big batched executables whole (``jax.experimental
+  big single-device batched executables whole (``jax.experimental
   .serialize_executable``; one blob file per ``(sim signature, batch
-  size, shard topology, backend)``), so the second process *loads* each
-  rung executable in ~0.1s with **no tracing at all**.  A loaded
+  size, shard topology, platform)``), so the second process *loads*
+  each rung executable in ~0.1s with **no tracing at all**.  Sharded
+  rungs are not persisted whole (``BatchRunner._batched_fn`` says why);
+  they still skip their compiles through the jax cache.  A loaded
   executable is the same compiled binary — results are bit-identical by
   construction, donation semantics included.
 * **Repro's own artifacts** — the executables are necessary but not
@@ -51,11 +57,12 @@ Every lookup emits ``cache.hit`` / ``cache.miss`` (and writes emit
 ``dse.cache.hit_rate`` gauge the ``/campaign`` dashboard surfaces —
 a campaign that silently misses its cache is a perf bug worth seeing.
 
-The directory is size-capped: :func:`gc` evicts least-recently-used
-files (executable hits bump mtime) down to ``REPRO_CACHE_MAX_BYTES`` /
-``configure(max_bytes=...)``, emitting ``cache.evict`` per file — so a
-long-lived shared cache dir serves many campaigns without growing
-forever.  The artifact store itself is never evicted (a few KB of
+The campaign directory (``REPRO_CACHE_DIR`` or :func:`configure`) holds
+the artifact store and the executable blobs.  It is size-capped:
+:func:`gc` evicts least-recently-used files (executable hits bump
+mtime) down to ``REPRO_CACHE_MAX_BYTES`` / ``configure(max_bytes=...)``,
+emitting ``cache.evict`` per file — so a long-lived shared cache dir
+serves many campaigns without growing forever.  The artifact store itself is never evicted (a few KB of
 decisions whose loss would cost a re-probe).
 
 Nothing here is load-bearing for correctness: with no cache dir
@@ -75,22 +82,42 @@ import threading
 import weakref
 
 import jax
+from jax._src import monitoring
 
 from repro.obs.bus import BUS
 
 ENV_DIR = "REPRO_CACHE_DIR"
 ENV_MAX_BYTES = "REPRO_CACHE_MAX_BYTES"
+ENV_JAX_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+# unset: one fixed directory at the root of the checkout (.gitignore'd)
+JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 # Bump when the artifact semantics change (keys embed it, so old stores
 # simply stop matching instead of poisoning new processes).
-CACHE_VERSION = 1
+CACHE_VERSION = 2
 
 STORE_NAME = "repro_dse_artifacts.json"
 
 _lock = threading.Lock()
 _cfg: dict = {"dir": None, "jax_enabled": False, "max_bytes": None}
 _store: "DseCache | None" = None
-_counts = {"hits": 0, "misses": 0, "writes": 0, "evictions": 0}
+_counts = {"hits": 0, "misses": 0, "writes": 0, "evictions": 0,
+           "write_failures": 0}
+_last_write_error: str | None = None    # repr of the newest failed write
+_jax_hits = 0       # compiles this process served from JAX's own cache
+
+
+def _count_jax_hit(event: str, **_) -> None:
+    global _jax_hits
+    if event == "/jax/compilation_cache/cache_hits":
+        _jax_hits += 1
+
+
+monitoring.register_event_listener(_count_jax_hit)
 
 _SIM_SIGS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
@@ -144,45 +171,48 @@ def active() -> bool:
     return cache_dir() is not None
 
 
-def ensure_enabled() -> bool:
-    """Idempotently wire the jax persistent compilation cache to the
-    configured directory; returns whether caching is active.
+def enable_jax_cache() -> str:
+    """Idempotently turn on JAX's persistent compilation cache; returns
+    its directory.
 
-    Drops jax's min-compile-time and min-entry-size thresholds so every
-    sweep executable persists (the default 1s floor would skip the small
-    liveness/rung programs whose re-compiles still stall a fresh
-    process).  Called by ``run_sweep`` on entry — the first sweep of a
-    process enables the cache for everything after it.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the
+    cache there and this sets no other directory; otherwise the cache
+    goes to :data:`JAX_CACHE_DIR`.  Drops jax's min-compile-time and
+    min-entry-size thresholds so every executable persists (the default
+    1s floor would skip the small liveness/rung programs whose
+    re-compiles still stall a fresh process).
     """
-    d = cache_dir()
-    if d is None:
-        return False
+    d = os.environ.get(ENV_JAX_DIR) or JAX_CACHE_DIR
     with _lock:
         if _cfg["jax_enabled"]:
-            return True
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        for knob, v in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(knob, v)
-            except (AttributeError, ValueError):  # pragma: no cover
-                pass                              # older jax: keep defaults
+            return d
+        if not os.environ.get(ENV_JAX_DIR):
+            jax.config.update("jax_compilation_cache_dir", d)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         # jax latches the enabled/disabled decision at the *first*
         # compile of the process: a build that jitted anything before
         # this point initialized the cache as "no directory", and the
         # config update alone never re-checks.  Un-latch so the next
-        # compile re-initializes against the directory we just set.
-        try:
-            from jax._src import compilation_cache as _cc
-            if getattr(_cc, "_cache_initialized", False) \
-                    and getattr(_cc, "_cache", None) is None:
-                _cc.reset_cache()
-        except Exception:             # pragma: no cover - jax internals
-            pass                      # moved: stay with latched behavior
+        # compile re-initializes against the directory set above.
+        from jax._src import compilation_cache as _cc
+        if _cc._cache_initialized and _cc._cache is None:
+            _cc.reset_cache()
         _cfg["jax_enabled"] = True
     if BUS.active:
         BUS.emit("cache.enable", dir=d, jax=jax.__version__)
+    return d
+
+
+def ensure_enabled() -> bool:
+    """Turn on JAX's persistent compilation cache (:func:`enable_jax_cache`)
+    when a campaign cache directory is configured; returns whether
+    campaign caching is active.  Called by ``run_sweep`` on entry — the
+    first sweep of a process enables the cache for everything after it.
+    """
+    if cache_dir() is None:
+        return False
+    enable_jax_cache()
     gc()     # shrink a pre-existing over-cap dir at startup, not mid-sweep
     return True
 
@@ -199,9 +229,20 @@ def store() -> "DseCache | None":
     return _store
 
 
+def jax_cache_hits() -> int:
+    """Compiles this process has served from JAX's persistent compilation
+    cache so far.  An executable JAX loaded from that cache re-serializes
+    into a blob that fails at run time (XLA:CPU "Function ... not found"),
+    so ``BatchRunner`` persists only executables it actually compiled."""
+    return _jax_hits
+
+
 def stats() -> dict:
-    """Process-wide artifact hit/miss/write counts (tests + dashboards)."""
-    return dict(_counts)
+    """Process-wide artifact hit/miss/write counts (tests + dashboards),
+    plus ``write_failures`` — executable blobs that could not be
+    serialized or written — and ``last_write_error``, the newest such
+    failure's ``repr`` (``None`` if there was none)."""
+    return dict(_counts, last_write_error=_last_write_error)
 
 
 def _note(kind: str, key: str, hit: bool, nbytes: int = 0) -> None:
@@ -221,8 +262,8 @@ def gc(limit: int | None = None) -> int:
     """Evict least-recently-used cache files until the directory fits
     the size cap; returns the number of files evicted.
 
-    Candidates are every file under the cache dir — AOT executable
-    blobs (``exec_*.bin``) and the jax compilation-cache entries —
+    Candidates are every file under the campaign cache dir — AOT
+    executable blobs (``exec_*.bin``) and anything else kept there —
     *except* the artifact store (:data:`STORE_NAME`, a few KB of
     decisions that regenerating would cost a re-probe) and in-progress
     temp files.  Recency is file mtime: :func:`get_executable` bumps it
@@ -498,9 +539,19 @@ def put_family_shape(build_key: str, shape_max: dict) -> None:
 # ---------------------------------------------------------------------------
 # whole-executable persistence (skips trace + lower, not just compile)
 # ---------------------------------------------------------------------------
+def _platform() -> str:
+    """Platform the next computation runs on: the ``jax.default_device``
+    in force (e.g. a CPU reference run beside a chip), else the default
+    backend.  A blob compiled for one platform must never serve another."""
+    d = jax.config.jax_default_device
+    if d is None:
+        return jax.default_backend()
+    return d if isinstance(d, str) else d.platform
+
+
 def _exec_key(sim, b: int, devices: int) -> str:
     return _key("exec", sim=sim_signature(sim), b=int(b),
-                devices=int(devices), platform=jax.default_backend())
+                devices=int(devices), platform=_platform())
 
 
 def _exec_path(key: str) -> str:
@@ -536,16 +587,28 @@ def get_executable(sim, b: int, devices: int):
     return fn
 
 
+def _write_failed(err: BaseException) -> None:
+    """Count a blob that could not be persisted (the sweep goes on with
+    the in-memory executable; ``stats()`` reports the failure)."""
+    global _last_write_error
+    _counts["write_failures"] += 1
+    _last_write_error = repr(err)
+    if BUS.active:
+        BUS.count("dse.cache.write_failures")
+
+
 def put_executable(sim, b: int, devices: int, compiled) -> None:
     """Serialize an AOT-compiled batched executable into the cache dir
-    (atomic write; silently skipped when serialization is unsupported)."""
+    (atomic write).  A failure to serialize or write is counted in
+    :func:`stats` (``write_failures``) and the run goes on uncached."""
     if not active():
         return
     key = _exec_key(sim, b, devices)
     try:
         from jax.experimental import serialize_executable as _se
         payload = pickle.dumps(_se.serialize(compiled))
-    except Exception:                  # pragma: no cover - jax internals
+    except Exception as e:
+        _write_failed(e)
         return
     d = cache_dir()
     os.makedirs(d, exist_ok=True)
@@ -554,11 +617,12 @@ def put_executable(sim, b: int, devices: int, compiled) -> None:
         with os.fdopen(fd, "wb") as fh:
             fh.write(payload)
         os.replace(tmp, _exec_path(key))
-    except OSError:                    # read-only dir: skip persistence
+    except OSError as e:               # read-only dir: skip persistence
         try:
             os.unlink(tmp)
         except OSError:
             pass
+        _write_failed(e)
         return
     _counts["writes"] += 1
     if BUS.active:

@@ -74,7 +74,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import SimParams, SimState, check_not_consumed
-from repro.core.pdes import LANE_AXIS, _SM_KW, lane_mesh, shard_map_compat
+from repro.core.pdes import LANE_AXIS, lane_mesh
 from repro.obs.bus import BUS
 
 from . import cache as dse_cache
@@ -159,7 +159,29 @@ def stack_states(state: SimState, n: int) -> SimState:
     alias each other or the input, so the result is safe to donate while
     ``state`` stays reusable as a template.
     """
+    return _stack(state, n)
+
+
+# Round assembly and compaction move lanes of whole state trees with
+# batch sizes that change from round to round.  Eager per-leaf ops
+# compile one program per (leaf, shape) — about a thousand compiles for a
+# 256-point campaign, each a fraction of a second on a TPU — where one
+# jitted program per shape combination moves every leaf at once.
+@functools.partial(jax.jit, static_argnums=1)
+def _stack(state, n: int):
     return jax.tree.map(lambda x: jnp.stack([x] * n), state)
+
+
+@jax.jit
+def _take(tree, idx):
+    """Lanes ``idx`` (in that order) of every leaf of a batched tree."""
+    return jax.tree.map(lambda x: x[idx], tree)
+
+
+@jax.jit
+def _cat(parts):
+    """Batched trees concatenated along the lane axis."""
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs), *parts)
 
 
 def stack_state_list(states: Sequence[SimState]) -> SimState:
@@ -279,7 +301,13 @@ class BatchRunner:
         if fn is not None:
             return fn
         sim = self.sim
-        if dse_cache.active():
+        # Whole executables persist for one device only: an AOT
+        # executable takes only the input layout it was compiled for,
+        # and round assembly hands a sharded rung whatever layout its
+        # gathers produced (e.g. replicated).  Sharded rungs still skip
+        # compiles through the JAX compilation cache.
+        persist = dse_cache.active() and d == 1
+        if persist:
             # whole-executable rehydrate: the persisted binary skips
             # trace + lower + compile entirely (bit-identical results —
             # it IS the executable a fresh compile would produce)
@@ -298,23 +326,25 @@ class BatchRunner:
             # one program over the whole mesh: each device traces the
             # same vmap over its b/d local lanes (SPMD — the DSE config
             # axis is embarrassingly parallel, so no collectives)
-            sm = shard_map_compat(
+            sm = jax.shard_map(
                 vm, mesh=lane_mesh(d),
                 in_specs=(P(LANE_AXIS),) * 4, out_specs=P(LANE_AXIS),
-                **_SM_KW)
+                check_vma=False)
             fn = jax.jit(sm, donate_argnums=(0,) if sim.donate else ())
         else:
             fn = jax.jit(
                 vm, donate_argnums=(0,) if sim.donate else ())
-        if dse_cache.active():
+        if persist:
             target, runner = fn, self
 
             def fn(s, p, u, m):
                 # AOT on first call so the compiled object is in hand
                 # to persist; lowering runs the python (same trace as
                 # the lazy jit path — trace_count telemetry holds)
+                hits = dse_cache.jax_cache_hits()
                 compiled = target.lower(s, p, u, m).compile()
-                dse_cache.put_executable(sim, b, d, compiled)
+                if dse_cache.jax_cache_hits() == hits:   # a real compile
+                    dse_cache.put_executable(sim, b, d, compiled)
                 runner._fns[key] = compiled
                 return compiled(s, p, u, m)
         self._fns[key] = fn
@@ -350,10 +380,7 @@ class BatchRunner:
                      n=self.trace_count - tc0,
                      dur=time.perf_counter() - t0)
         for a in (live, ep):
-            try:
-                a.copy_to_host_async()
-            except AttributeError:    # older jax array types: sync get
-                pass
+            a.copy_to_host_async()
         return (live, ep, b)
 
     def _liveness_read(self, pending):
@@ -691,9 +718,9 @@ class BatchRunner:
                     ids += seg_ids
                     room -= len(seg_ids)
                 else:                 # split a segment across rounds
-                    parts.append(jax.tree.map(lambda x: x[:room], seg))
-                    pool[0] = (seg_ids[room:],
-                               jax.tree.map(lambda x: x[room:], seg))
+                    cut = np.arange(len(seg_ids), dtype=np.int32)
+                    parts.append(_take(seg, cut[:room]))
+                    pool[0] = (seg_ids[room:], _take(seg, cut[room:]))
                     ids += seg_ids[:room]
                     room = 0
             n_fresh = min(room, len(pending))
@@ -707,8 +734,7 @@ class BatchRunner:
             if room:                  # zero-horizon padding: freezes on
                 parts.append(stack_states(pad_template, room))  # entry
                 ids += [-1] * room
-            sb = (parts[0] if len(parts) == 1 else
-                  jax.tree.map(lambda *xs: jnp.concatenate(xs), *parts))
+            sb = parts[0] if len(parts) == 1 else _cat(parts)
 
             rows = np.asarray(ids, np.int32)
             live_row = rows >= 0
@@ -716,7 +742,7 @@ class BatchRunner:
             if C == B and np.array_equal(ridx, np.arange(B)):
                 pb = params_b         # identity round: skip the gather
             else:
-                pb = jax.tree.map(lambda x: x[jnp.asarray(ridx)], params_b)
+                pb = _take(params_b, ridx)
             u_vec = np.where(live_row, u[ridx], 0.0).astype(np.float32)
             cap = budget[ridx].astype(np.int64) if endgame else \
                 np.minimum(ep[ridx] + schedule.quantum,
@@ -788,16 +814,14 @@ class BatchRunner:
                 if len(fin_rows) == C:
                     done.append((fin_ids, out))
                 else:
-                    g = jnp.asarray(np.asarray(fin_rows, np.int32))
-                    done.append((fin_ids,
-                                 jax.tree.map(lambda x: x[g], out)))
+                    done.append((fin_ids, _take(
+                        out, np.asarray(fin_rows, np.int32))))
             if surv_rows:
                 if len(surv_rows) == C:
                     pool.append((surv_ids, out))
                 else:
-                    g = jnp.asarray(np.asarray(surv_rows, np.int32))
-                    pool.append((surv_ids,
-                                 jax.tree.map(lambda x: x[g], out)))
+                    pool.append((surv_ids, _take(
+                        out, np.asarray(surv_rows, np.int32))))
             host_s = rec["host_s"] + (time.perf_counter() - h0)
             host_accum += host_s
             wait_accum += wait_s
@@ -874,14 +898,12 @@ class BatchRunner:
         # once, then one gather per leaf restores lane order
         all_ids = np.asarray([i for ids, _ in done for i in ids], np.int32)
         full = (done[0][1] if len(done) == 1 else
-                jax.tree.map(lambda *xs: jnp.concatenate(xs),
-                             *[t for _, t in done]))
+                _cat([t for _, t in done]))
         if np.array_equal(all_ids, np.arange(B)):
             return full               # already in point order
         pos = np.empty(B, np.int32)
         pos[all_ids] = np.arange(B, dtype=np.int32)
-        g = jnp.asarray(pos)
-        return jax.tree.map(lambda x: x[g], full)
+        return _take(full, pos)
 
 
 # ---------------------------------------------------------------------------

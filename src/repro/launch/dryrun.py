@@ -76,10 +76,11 @@ def run_sim_cell(multi_pod: bool) -> dict:
     import jax
 
     from repro.launch import roofline
+    from repro.launch.mesh import make_sim_mesh
     from repro.sims.memsys import build_sharded_memsys
 
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("sim",))
+    mesh = make_sim_mesh(n)
     t0 = time.time()
     ss = build_sharded_memsys(mesh=mesh, n_shards=n, tiles_per_shard=4)
     lowered = ss.lower(until=4096.0)

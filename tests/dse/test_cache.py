@@ -174,6 +174,11 @@ def test_second_process_performs_zero_compiles(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src")
     env["REPRO_CACHE_DIR"] = str(tmp_path / "shared_cache")
+    # a fresh jax compilation cache for this pair of processes (where
+    # the variable is set, the program keeps jax's cache there): the
+    # checkout's fixed cache may already hold every entry, and p1 must
+    # compile
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
 
     def run():
         r = subprocess.run([sys.executable, "-c", WORKER],
@@ -193,6 +198,38 @@ def test_second_process_performs_zero_compiles(tmp_path):
     assert second["hits"] > 0
     assert first["artifacts"]["writes"] > 0
     assert second["artifacts"]["hits"] > 0
+
+
+@pytest.mark.slow
+def test_executables_served_by_jax_cache_are_not_persisted(tmp_path):
+    """A rung executable JAX loaded from its own persistent cache is not
+    re-persisted as a blob: such a blob loads, then fails at run time.
+    With the blobs gone but JAX's cache warm, a process compiles nothing
+    new, writes no blob, and the next process still runs the sweep."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    env["REPRO_CACHE_DIR"] = str(tmp_path / "shared_cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
+
+    def run():
+        r = subprocess.run([sys.executable, "-c", WORKER],
+                           capture_output=True, text=True, timeout=900,
+                           env=env)
+        assert r.returncode == 0, r.stderr[-4000:]
+        return json.loads(r.stdout.strip().splitlines()[-1])
+
+    def blobs():
+        return sorted(f for f in os.listdir(env["REPRO_CACHE_DIR"])
+                      if f.startswith("exec_"))
+
+    first = run()
+    assert blobs()                                  # fresh compiles persist
+    for f in blobs():
+        os.unlink(os.path.join(env["REPRO_CACHE_DIR"], f))
+    second = run()                                  # JAX cache serves all
+    assert second["misses"] == 0 and blobs() == [], (second, blobs())
+    assert run()["rows"] == second["rows"] == first["rows"]
 
 
 # ---------------------------------------------------------------------------
@@ -256,16 +293,35 @@ def test_configure_max_bytes_and_env_fallback(tmp_path, monkeypatch):
 def test_put_executable_triggers_gc(cache_dir, monkeypatch):
     """Writes keep the dir under the cap automatically: after an
     oversized put, older blobs are gone."""
+    import pickle
+
+    import jax, jax.numpy as jnp
+    from jax.experimental import serialize_executable
     os.makedirs(cache_dir, exist_ok=True)
     _fake_blob(cache_dir, "exec_old0.bin", 2000, age_s=100)
     _fake_blob(cache_dir, "exec_old1.bin", 2000, age_s=50)
-    dse_cache.configure(cache_dir, max_bytes=4500)
     sim, st = build(n_cores=2, n_reqs=6, donate=False)
-    # a real AOT executable write (size ~O(10KB)) blows the cap; both
-    # old blobs must age out while the fresh write survives
-    import jax, jax.numpy as jnp
     compiled = jax.jit(lambda x: x + 1).lower(jnp.zeros(4)).compile()
+    # the cap holds the fresh blob plus less than one old blob, sized
+    # from the blob itself (its size varies with the jax version): the
+    # real AOT write blows the cap, both old blobs must age out while
+    # the fresh write survives
+    blob = len(pickle.dumps(serialize_executable.serialize(compiled)))
+    dse_cache.configure(cache_dir, max_bytes=blob + 1000)
     dse_cache.put_executable(sim, 4, 1, compiled)
     left = sorted(os.listdir(cache_dir))
     assert "exec_old0.bin" not in left and "exec_old1.bin" not in left
     assert any(f.startswith("exec_") and f.endswith(".bin") for f in left)
+
+
+def test_put_executable_counts_failed_writes(cache_dir):
+    """A blob that cannot be serialized is counted and named in
+    ``stats()``, not dropped in silence; the caller goes on uncached."""
+    os.makedirs(cache_dir, exist_ok=True)
+    sim, _ = build(n_cores=2, n_reqs=6, donate=False)
+    before = dse_cache.stats()["write_failures"]
+    dse_cache.put_executable(sim, 4, 1, object())     # not an executable
+    s = dse_cache.stats()
+    assert s["write_failures"] == before + 1
+    assert s["last_write_error"]
+    assert not any(f.startswith("exec_") for f in os.listdir(cache_dir))

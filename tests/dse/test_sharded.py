@@ -222,3 +222,31 @@ def test_sharded_rebalance_telemetry_two_devices():
         print("REBALANCE_OK")
     """)
     assert "REBALANCE_OK" in out
+
+
+@pytest.mark.slow
+def test_sharded_sweep_with_campaign_cache_two_devices(tmp_path):
+    """With a campaign cache configured, sharded rounds run the same
+    rows as the unsharded sweep: compaction hands a sharded rung
+    replicated gathers, which an AOT executable compiled for another
+    input layout refuses — so only single-device rungs persist whole."""
+    out = _run_two_device(f"""
+        import numpy as np
+        from repro.dse import SweepSpec, cache, run_sweep
+        from repro.sims.memsys import build
+        cache.configure({str(tmp_path)!r})
+        B = 32
+        spec = SweepSpec.explicit(
+            [{{"conn_latency[-1]": 10.0 + i,
+               "kind.l1.extra_hit_rate": 0.8 * ((i * 7) % B) / (B - 1)}}
+             for i in range(B)])
+        u = (250.0 + 1750.0 * ((np.arange(B) * 11) % B) / (B - 1)) \\
+            .astype(np.float32)
+        def bf():
+            return build(n_cores=4, n_reqs=32)
+        sharded = run_sweep(bf, spec, until=u, shard=True)
+        assert sharded == run_sweep(bf, spec, until=u)
+        assert cache.stats()["write_failures"] == 0, cache.stats()
+        print("CACHED_SHARDED_OK")
+    """)
+    assert "CACHED_SHARDED_OK" in out

@@ -118,7 +118,6 @@ def test_int8_allreduce_error_feedback_converges():
     """Compressed DP training still converges on a quadratic (shard_map)."""
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.core.pdes import shard_map_compat
     from repro.optim.compress import int8_allreduce_grads
 
     mesh = jax.make_mesh((1,), ("data",))
@@ -127,7 +126,7 @@ def test_int8_allreduce_error_feedback_converges():
     err = {"x": jnp.zeros(2)}
 
     for _ in range(150):
-        @partial(shard_map_compat, mesh=mesh, in_specs=(P(), P(), P()),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P(), P()),
                  out_specs=(P(), P()))
         def reduced(p, e, t):
             g = {"x": 2 * (p - t)}
