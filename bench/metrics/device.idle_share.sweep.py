@@ -1,0 +1,9 @@
+"""device.idle_share.sweep: per cent of the traced window in which no
+operation ran on the device (profiler trace)."""
+
+
+def read(run):
+    t = run.trace
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
