@@ -71,6 +71,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.bus import BUS
+
 from .component import ComponentKind, KindHandle, normalize_tick_output
 from .message import MSG_WORDS, W_DST, W_TIME, f2i, i2f
 from .ports import EPS, Ports
@@ -515,36 +517,38 @@ class Simulation:
 
     # ------------------------------------------------------------------
     def init_state(self) -> SimState:
-        cap, w = self.cap_phys, MSG_WORDS
-        next_tick = []
-        for k in self.kinds:
-            t0 = INF if k.start_asleep else 0.0
-            next_tick.append(jnp.full((k.n_instances,), t0, jnp.float32))
-        seg = lambda shape_fn: {kc.name: shape_fn(kc) for kc in self._kc}
-        zeros_np = lambda kc: jnp.zeros((kc.np_k,), jnp.int32)
-        zeros_buf = lambda kc: jnp.zeros((kc.np_k, cap, w), jnp.int32)
-        # copy user-supplied init pytrees: donation must never delete (or
-        # double-donate aliases of) the builder's arrays
-        comp_state = jax.tree.map(
-            jnp.copy, {k.name: k.init_state for k in self.kinds})
-        return SimState(
-            time=jnp.float32(0.0),
-            next_tick=(jnp.concatenate(next_tick) if next_tick
-                       else jnp.zeros((0,), jnp.float32)),
-            conn_wake=jnp.full((self.n_conn,), INF),
-            comp_state=comp_state,
-            in_buf=seg(zeros_buf), in_head=seg(zeros_np), in_cnt=seg(zeros_np),
-            out_buf=seg(zeros_buf), out_head=seg(zeros_np),
-            out_cnt=seg(zeros_np),
-            rr=jnp.zeros((self.n_conn,), jnp.int32),
-            stats=Stats.zero(self.n_comp),
-            # min 1 row: zero-sized arrays break shard_map sharding (pdes)
-            buf_samples=jnp.zeros((max(self.max_samples, 1), self.n_ports_g),
-                                  jnp.int32),
-            sample_idx=jnp.int32(0),
-            next_sample=jnp.float32(self.sample_period if self.sample_period
-                                    else jnp.inf),
-        )
+        with BUS.span("engine.init_state"):
+            cap, w = self.cap_phys, MSG_WORDS
+            next_tick = []
+            for k in self.kinds:
+                t0 = INF if k.start_asleep else 0.0
+                next_tick.append(jnp.full((k.n_instances,), t0, jnp.float32))
+            seg = lambda shape_fn: {kc.name: shape_fn(kc) for kc in self._kc}
+            zeros_np = lambda kc: jnp.zeros((kc.np_k,), jnp.int32)
+            zeros_buf = lambda kc: jnp.zeros((kc.np_k, cap, w), jnp.int32)
+            # copy user-supplied init pytrees: donation must never delete (or
+            # double-donate aliases of) the builder's arrays
+            comp_state = jax.tree.map(
+                jnp.copy, {k.name: k.init_state for k in self.kinds})
+            return SimState(
+                time=jnp.float32(0.0),
+                next_tick=(jnp.concatenate(next_tick) if next_tick
+                           else jnp.zeros((0,), jnp.float32)),
+                conn_wake=jnp.full((self.n_conn,), INF),
+                comp_state=comp_state,
+                in_buf=seg(zeros_buf), in_head=seg(zeros_np),
+                in_cnt=seg(zeros_np),
+                out_buf=seg(zeros_buf), out_head=seg(zeros_np),
+                out_cnt=seg(zeros_np),
+                rr=jnp.zeros((self.n_conn,), jnp.int32),
+                stats=Stats.zero(self.n_comp),
+                # min 1 row: zero-sized arrays break shard_map sharding (pdes)
+                buf_samples=jnp.zeros(
+                    (max(self.max_samples, 1), self.n_ports_g), jnp.int32),
+                sample_idx=jnp.int32(0),
+                next_sample=jnp.float32(
+                    self.sample_period if self.sample_period else jnp.inf),
+            )
 
     def _port_min_to_comp(self, wake_port):
         """Per-port wake times [PG] -> per-component wake times [NC] by a
@@ -688,153 +692,163 @@ class Simulation:
         for ki, kind in enumerate(self.kinds):
             kc = self._kc[ki]
             n, p, name = kc.n, kc.p, kc.name
-            periods_k = P.periods[name]
-            if self.naive:
-                r = jnp.remainder(t, periods_k)
-                mask = (jnp.abs(r) < EPS) | (jnp.abs(r - periods_k) < EPS)
+            with jax.named_scope(f"engine.tick.{name}"):
+                periods_k = P.periods[name]
+                if self.naive:
+                    r = jnp.remainder(t, periods_k)
+                    mask = (jnp.abs(r) < EPS) | (jnp.abs(r - periods_k) < EPS)
+                else:
+                    mask = next_tick[kc.csl] <= t + EPS
+                if P.inst_mask is not None:
+                    # family activity mask: masked-off instances never tick
+                    # (and therefore never count toward ticks/progress/busy)
+                    mask = mask & P.inst_mask[name]
+
+                sh = lambda a: a.reshape(n, p, *a.shape[1:])
+                # kind params are closed over, not vmapped: every instance of a
+                # kind sees the same (possibly traced) parameter pytree
+                kp = P.kind.get(name, {})
+                wants_params = kind.params is not None
+
+                def one(st_i, ib, ih, ic, ob, oh, oc, cp, g, pe, kind=kind,
+                        kp=kp, wants_params=wants_params):
+                    ports = Ports(ib, ih, ic, ob, oh, oc, cp, g, pe, tf)
+                    out = (kind.tick_fn(st_i, ports, tf, kp) if wants_params
+                           else kind.tick_fn(st_i, ports, tf))
+                    st2, ports2, res = normalize_tick_output(out)
+                    return (st2, ports2.in_buf, ports2.in_head, ports2.in_cnt,
+                            ports2.out_buf, ports2.out_head, ports2.out_cnt,
+                            res.progress, res.next_time)
+
+                (st2, ib2, ih2, ic2, ob2, oh2, oc2, prog, nxt) = jax.vmap(one)(
+                    comp_state[name], sh(in_buf[name]), sh(in_head[name]),
+                    sh(in_cnt[name]), sh(out_buf[name]), sh(out_head[name]),
+                    sh(out_cnt[name]), kc.caps, kc.gid, kc.peer)
+
+                def sel(new, old, m=mask):
+                    mm = m.reshape(m.shape + (1,) * (new.ndim - 1))
+                    return jnp.where(mm, new, old)
+
+                comp_state[name] = jax.tree.map(
+                    lambda a, b: sel(a, b), st2, comp_state[name])
+                fl = lambda a: a.reshape(n * p, *a.shape[2:])
+                pmask = jnp.repeat(mask, p)
+
+                def psel(new, old):
+                    mm = pmask.reshape(pmask.shape + (1,) * (new.ndim - 1))
+                    return jnp.where(mm, new, old)
+
+                ic_old, oc_old = in_cnt[name], out_cnt[name]
+                in_buf[name] = psel(fl(ib2), in_buf[name])
+                in_head[name] = psel(fl(ih2), in_head[name])
+                in_cnt[name] = psel(fl(ic2), in_cnt[name])
+                out_buf[name] = psel(fl(ob2), out_buf[name])
+                out_head[name] = psel(fl(oh2), out_head[name])
+                out_cnt[name] = psel(fl(oc2), out_cnt[name])
+
+                prog = prog & mask
+                if not self.naive:
+                    # Rule 3: progress => next cycle; no progress => sleep.
+                    base = jnp.where(prog, _align_after(t, periods_k), INF)
+                    custom = jnp.where(nxt > -0.5,
+                                       jnp.maximum(nxt, t + EPS), base)
+                    # In-flight arrivals: a ticked component must not sleep
+                    # past the ready time of a message already in its
+                    # buffers (rule 1 for arrivals whose delivery preceded
+                    # this tick).  Ready-now messages do NOT re-wake —
+                    # unblocking is backprop's job.
+                    hb = in_buf[name][:, :, W_TIME]             # [n*p, CAP]
+                    hOH = in_head[name][:, None] == self._acap  # one-hot
+                    hr = i2f(jnp.sum(hb * hOH.astype(jnp.int32), axis=1))
+                    pend = (in_cnt[name] > 0) & (hr > t + EPS)
+                    w = jnp.where(pend, hr, INF).reshape(n, p)
+                    arr = _align_at_or_after(jnp.min(w, axis=1), periods_k)
+                    custom = jnp.minimum(custom, arr)
+                    next_tick = next_tick.at[kc.csl].set(
+                        jnp.where(mask, custom, next_tick[kc.csl]))
+
+                # Availability Backpropagation (backward half): incoming
+                # buffer full->not-full wakes the serving connection; any
+                # new send wakes the connection too.
+                ic_new, oc_new = in_cnt[name], out_cnt[name]
+                in_freed = (ic_old == kc.caps_f) & (ic_new < kc.caps_f)
+                wake_p_segs[name] = in_freed | (oc_new > oc_old)
+
+                total_ticks += jnp.sum(mask.astype(jnp.int32))
+                total_prog += jnp.sum(prog.astype(jnp.int32))
+                busy = busy.at[kc.csl].add(prog.astype(jnp.int32))
+
+        with jax.named_scope("engine.update"):
+            # a connection wakes iff any of its (static) member ports asked —
+            # static take through the member matrix instead of a scatter-min
+            if self.kinds:
+                wake_p_f = self._flat(wake_p_segs)
+                wake_pad = jnp.concatenate([wake_p_f, jnp.zeros((1,), bool)])
+                conn_asked = jnp.any(wake_pad[self._member_sent_np], axis=1)
+                wake_conn = jnp.where(conn_asked, wake1, INF)
             else:
-                mask = next_tick[kc.csl] <= t + EPS
-            if P.inst_mask is not None:
-                # family activity mask: masked-off instances never tick
-                # (and therefore never count toward ticks/progress/busy)
-                mask = mask & P.inst_mask[name]
+                wake_conn = jnp.full((self.n_conn,), INF)
 
-            sh = lambda a: a.reshape(n, p, *a.shape[1:])
-            # kind params are closed over, not vmapped: every instance of a
-            # kind sees the same (possibly traced) parameter pytree
-            kp = P.kind.get(name, {})
-            wants_params = kind.params is not None
-
-            def one(st_i, ib, ih, ic, ob, oh, oc, cp, g, pe, kind=kind,
-                    kp=kp, wants_params=wants_params):
-                ports = Ports(ib, ih, ic, ob, oh, oc, cp, g, pe, tf)
-                out = (kind.tick_fn(st_i, ports, tf, kp) if wants_params
-                       else kind.tick_fn(st_i, ports, tf))
-                st2, ports2, res = normalize_tick_output(out)
-                return (st2, ports2.in_buf, ports2.in_head, ports2.in_cnt,
-                        ports2.out_buf, ports2.out_head, ports2.out_cnt,
-                        res.progress, res.next_time)
-
-            (st2, ib2, ih2, ic2, ob2, oh2, oc2, prog, nxt) = jax.vmap(one)(
-                comp_state[name], sh(in_buf[name]), sh(in_head[name]),
-                sh(in_cnt[name]), sh(out_buf[name]), sh(out_head[name]),
-                sh(out_cnt[name]), kc.caps, kc.gid, kc.peer)
-
-            def sel(new, old, m=mask):
-                mm = m.reshape(m.shape + (1,) * (new.ndim - 1))
-                return jnp.where(mm, new, old)
-
-            comp_state[name] = jax.tree.map(
-                lambda a, b: sel(a, b), st2, comp_state[name])
-            fl = lambda a: a.reshape(n * p, *a.shape[2:])
-            pmask = jnp.repeat(mask, p)
-
-            def psel(new, old):
-                mm = pmask.reshape(pmask.shape + (1,) * (new.ndim - 1))
-                return jnp.where(mm, new, old)
-
-            ic_old, oc_old = in_cnt[name], out_cnt[name]
-            in_buf[name] = psel(fl(ib2), in_buf[name])
-            in_head[name] = psel(fl(ih2), in_head[name])
-            in_cnt[name] = psel(fl(ic2), in_cnt[name])
-            out_buf[name] = psel(fl(ob2), out_buf[name])
-            out_head[name] = psel(fl(oh2), out_head[name])
-            out_cnt[name] = psel(fl(oc2), out_cnt[name])
-
-            prog = prog & mask
-            if not self.naive:
-                # Rule 3: progress => next cycle; no progress => sleep.
-                base = jnp.where(prog, _align_after(t, periods_k), INF)
-                custom = jnp.where(nxt > -0.5, jnp.maximum(nxt, t + EPS), base)
-                # In-flight arrivals: a ticked component must not sleep past
-                # the ready time of a message already in its buffers (rule 1
-                # for arrivals whose delivery preceded this tick).  Ready-now
-                # messages do NOT re-wake — unblocking is backprop's job.
-                hb = in_buf[name][:, :, W_TIME]             # [n*p, CAP]
-                hOH = in_head[name][:, None] == self._acap  # one-hot gather
-                hr = i2f(jnp.sum(hb * hOH.astype(jnp.int32), axis=1))
-                pend = (in_cnt[name] > 0) & (hr > t + EPS)
-                w = jnp.where(pend, hr, INF).reshape(n, p)
-                arr = _align_at_or_after(jnp.min(w, axis=1), periods_k)
-                custom = jnp.minimum(custom, arr)
-                next_tick = next_tick.at[kc.csl].set(
-                    jnp.where(mask, custom, next_tick[kc.csl]))
-
-            # Availability Backpropagation (backward half): incoming buffer
-            # full->not-full wakes the serving connection; any new send wakes
-            # the connection too.
-            ic_new, oc_new = in_cnt[name], out_cnt[name]
-            in_freed = (ic_old == kc.caps_f) & (ic_new < kc.caps_f)
-            wake_p_segs[name] = in_freed | (oc_new > oc_old)
-
-            total_ticks += jnp.sum(mask.astype(jnp.int32))
-            total_prog += jnp.sum(prog.astype(jnp.int32))
-            busy = busy.at[kc.csl].add(prog.astype(jnp.int32))
-
-        # a connection wakes iff any of its (static) member ports asked —
-        # static take through the member matrix instead of a scatter-min
-        if self.kinds:
-            wake_p_f = self._flat(wake_p_segs)
-            wake_pad = jnp.concatenate([wake_p_f, jnp.zeros((1,), bool)])
-            conn_asked = jnp.any(wake_pad[self._member_sent_np], axis=1)
-            wake_conn = jnp.where(conn_asked, wake1, INF)
-        else:
-            wake_conn = jnp.full((self.n_conn,), INF)
-
-        stats = dataclasses.replace(
-            s.stats, ticks=s.stats.ticks + total_ticks,
-            progress_ticks=s.stats.progress_ticks + total_prog, busy=busy)
-        s = dataclasses.replace(
-            s, next_tick=next_tick, comp_state=comp_state, in_buf=in_buf,
-            in_head=in_head, in_cnt=in_cnt, out_buf=out_buf,
-            out_head=out_head, out_cnt=out_cnt, stats=stats)
+            stats = dataclasses.replace(
+                s.stats, ticks=s.stats.ticks + total_ticks,
+                progress_ticks=s.stats.progress_ticks + total_prog, busy=busy)
+            s = dataclasses.replace(
+                s, next_tick=next_tick, comp_state=comp_state, in_buf=in_buf,
+                in_head=in_head, in_cnt=in_cnt, out_buf=out_buf,
+                out_head=out_head, out_cnt=out_cnt, stats=stats)
         return s, wake_conn
 
     # ------------------------------------------------------------------
     def _epoch(self, s: SimState, P: SimParams):
-        if self.naive:
-            t = s.time  # process the current cycle, then advance by one
-            active = jnp.ones((self.n_conn,), bool)
-        else:
-            t = jnp.minimum(jnp.min(s.next_tick) if self.n_comp else INF,
-                            jnp.min(s.conn_wake))
-            if self.max_samples:
-                t = jnp.minimum(t, s.next_sample)
-            active = s.conn_wake <= t + EPS
-        if P.conn_mask is not None:
-            # family activity mask: masked-off connections never deliver
-            active = active & P.conn_mask
+        # the named scopes only label the ops in the compiled program's
+        # metadata (profiler op names); they change no computation
+        with jax.named_scope("engine.next_event"):
+            if self.naive:
+                t = s.time  # process the current cycle, then advance by one
+                active = jnp.ones((self.n_conn,), bool)
+            else:
+                t = jnp.minimum(jnp.min(s.next_tick) if self.n_comp else INF,
+                                jnp.min(s.conn_wake))
+                if self.max_samples:
+                    t = jnp.minimum(t, s.next_sample)
+                active = s.conn_wake <= t + EPS
+            if P.conn_mask is not None:
+                # family activity mask: masked-off connections never deliver
+                active = active & P.conn_mask
+            wake1 = _align_after(t, 1.0)      # shared next-cycle wake point
 
-        wake1 = _align_after(t, 1.0)          # shared next-cycle wake point
         s = dataclasses.replace(s, time=t)
-        s, wake_comp = self._deliver(s, P, t, active, wake1)
+        with jax.named_scope("engine.deliver"):
+            s, wake_comp = self._deliver(s, P, t, active, wake1)
         s, wake_conn = self._tick_kinds(s, P, t, wake1)
-        next_tick = jnp.minimum(s.next_tick, wake_comp)
-        conn_wake = jnp.minimum(s.conn_wake, wake_conn)
-        # Masked-off rows are pinned to +inf by broadcast selects so the
-        # next-event min never schedules them — the mask's only entry
-        # points into the wake reductions (no gathers/scatters involved).
-        if P.inst_mask is not None:
-            next_tick = jnp.where(self._flat_inst_mask(P.inst_mask),
-                                  next_tick, INF)
-        if P.conn_mask is not None:
-            conn_wake = jnp.where(P.conn_mask, conn_wake, INF)
-        s = dataclasses.replace(
-            s, next_tick=next_tick, conn_wake=conn_wake,
-            stats=dataclasses.replace(s.stats, epochs=s.stats.epochs + 1))
-        if self.max_samples:
-            do = s.next_sample <= t + EPS
-            row = s.sample_idx % self.max_samples
+        with jax.named_scope("engine.update"):
+            next_tick = jnp.minimum(s.next_tick, wake_comp)
+            conn_wake = jnp.minimum(s.conn_wake, wake_conn)
+            # Masked-off rows are pinned to +inf by broadcast selects so the
+            # next-event min never schedules them — the mask's only entry
+            # points into the wake reductions (no gathers/scatters involved).
+            if P.inst_mask is not None:
+                next_tick = jnp.where(self._flat_inst_mask(P.inst_mask),
+                                      next_tick, INF)
+            if P.conn_mask is not None:
+                conn_wake = jnp.where(P.conn_mask, conn_wake, INF)
             s = dataclasses.replace(
-                s,
-                buf_samples=jnp.where(
-                    do, s.buf_samples.at[row].set(self._flat(s.in_cnt)),
-                    s.buf_samples),
-                sample_idx=s.sample_idx + do.astype(jnp.int32),
-                next_sample=jnp.where(do, s.next_sample + self.sample_period,
-                                      s.next_sample))
-        if self.naive:
-            s = dataclasses.replace(s, time=t + 1.0)
+                s, next_tick=next_tick, conn_wake=conn_wake,
+                stats=dataclasses.replace(s.stats, epochs=s.stats.epochs + 1))
+            if self.max_samples:
+                do = s.next_sample <= t + EPS
+                row = s.sample_idx % self.max_samples
+                s = dataclasses.replace(
+                    s,
+                    buf_samples=jnp.where(
+                        do, s.buf_samples.at[row].set(self._flat(s.in_cnt)),
+                        s.buf_samples),
+                    sample_idx=s.sample_idx + do.astype(jnp.int32),
+                    next_sample=jnp.where(
+                        do, s.next_sample + self.sample_period,
+                        s.next_sample))
+            if self.naive:
+                s = dataclasses.replace(s, time=t + 1.0)
         return s
 
     def _next_event(self, s: SimState):
@@ -900,7 +914,8 @@ class Simulation:
         for this run (see :class:`SimParams` / ``default_params()``); its
         leaves are never donated.  ``None`` runs the build-time defaults."""
         assert until < 2 ** 24, "float32 cycle precision bound (DESIGN.md)"
-        if self.donate:
-            check_not_consumed(state)
-        return self._run_jit(state, until, max_epochs=max_epochs,
-                             params=params)
+        with BUS.span("engine.run"):
+            if self.donate:
+                check_not_consumed(state)
+            return self._run_jit(state, until, max_epochs=max_epochs,
+                                 params=params)

@@ -180,7 +180,9 @@ def enable_jax_cache() -> str:
     goes to :data:`JAX_CACHE_DIR`.  Drops jax's min-compile-time and
     min-entry-size thresholds so every executable persists (the default
     1s floor would skip the small liveness/rung programs whose
-    re-compiles still stall a fresh process).
+    re-compiles still stall a fresh process), and keys entries on the
+    programs' op metadata too (source locations, ``jax.named_scope``
+    names), so that a profile names the phases of the code that runs.
     """
     d = os.environ.get(ENV_JAX_DIR) or JAX_CACHE_DIR
     with _lock:
@@ -190,6 +192,19 @@ def enable_jax_cache() -> str:
             jax.config.update("jax_compilation_cache_dir", d)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        # an executable keeps the op metadata of the code that compiled
+        # it; the engine's named scopes change only that metadata, so
+        # without it in the key a profile of a cached executable names
+        # the scopes of whatever code first compiled the same program.
+        # Each op's location keeps one frame, its own source line, not
+        # the call stack above it, and its file's base name, not the
+        # checkout's path, so that a program keys alike from every call
+        # site (a warm-up, a round) and every checkout of the same code
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+        jax.config.update("jax_traceback_in_locations_limit", 1)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          r".*/")
         # jax latches the enabled/disabled decision at the *first*
         # compile of the process: a build that jitted anything before
         # this point initialized the cache as "no directory", and the

@@ -675,104 +675,109 @@ class BatchRunner:
         inflight: "collections.deque" = collections.deque()
 
         def dispatch():
-            """Assemble one round from the pool + pending queue and
-            enqueue its device step and async liveness pull.  Pure host
-            and dispatch work — never blocks on the device, so it runs
-            concurrently with the previous round's compute."""
+            """Assemble one round from the pool + pending queue
+            (``round.assemble``) and enqueue its device step and async
+            liveness pull (``round.launch``).  Pure host and dispatch
+            work — never blocks on the device, so it runs concurrently
+            with the previous round's compute.  Only the assembly counts
+            toward the round's ``host_s``: the launch (``run_batch`` and
+            the liveness start) is in neither ``host_s`` nor ``wait_s``."""
             nonlocal tuner, schedule, pending, n_dispatched
             h0 = time.perf_counter()
-            n_alive = sum(len(ids) for ids, _ in pool)
-            remaining = n_alive + len(pending)
-            rung = None
-            if tuner is not None:
-                rung = tuner.next_probe(remaining)
-                if rung is None:              # probing done: pick winner
-                    top = tuner.best(schedule.top)
-                    if BUS.active:
-                        BUS.emit("autotune.winner", top=top,
-                                 rates={str(r): rate for r, rate
-                                        in tuner.rates.items()})
-                    schedule = schedule.narrowed(top)
-                    self._tuned_top[d] = top
-                    dse_cache.put_tuned_top(self.sim, d, top)
-                    tuner = None
-            C = rung if rung is not None else schedule.size_for(remaining)
-            # Endgame: once everything left fits the smallest rung there
-            # is nothing to compact *into* and no queue to refill from —
-            # quantum rounds would be pure overhead, so run to the full
-            # budget in one round (this is also the whole story for
-            # B <= the smallest rung: one round, monolithic-equivalent).
-            # Needs *every* lane resolved, so only when nothing is in
-            # flight (in-flight survivors may still need this rung).
-            endgame = (tuner is None and not inflight
-                       and remaining <= schedule.ladder[-1])
+            with BUS.span("round.assemble"):
+                n_alive = sum(len(ids) for ids, _ in pool)
+                remaining = n_alive + len(pending)
+                rung = None
+                if tuner is not None:
+                    rung = tuner.next_probe(remaining)
+                    if rung is None:              # probing done: pick winner
+                        top = tuner.best(schedule.top)
+                        if BUS.active:
+                            BUS.emit("autotune.winner", top=top,
+                                     rates={str(r): rate for r, rate
+                                            in tuner.rates.items()})
+                        schedule = schedule.narrowed(top)
+                        self._tuned_top[d] = top
+                        dse_cache.put_tuned_top(self.sim, d, top)
+                        tuner = None
+                C = rung if rung is not None else schedule.size_for(remaining)
+                # Endgame: once everything left fits the smallest rung there
+                # is nothing to compact *into* and no queue to refill from —
+                # quantum rounds would be pure overhead, so run to the full
+                # budget in one round (this is also the whole story for
+                # B <= the smallest rung: one round, monolithic-equivalent).
+                # Needs *every* lane resolved, so only when nothing is in
+                # flight (in-flight survivors may still need this rung).
+                endgame = (tuner is None and not inflight
+                           and remaining <= schedule.ladder[-1])
 
-            # --- assemble the round's batch: survivors, refill, pad ----
-            parts, ids = [], []
-            room = C
-            while pool and room:
-                seg_ids, seg = pool[0]
-                if len(seg_ids) <= room:
-                    pool.pop(0)
-                    parts.append(seg)
-                    ids += seg_ids
-                    room -= len(seg_ids)
-                else:                 # split a segment across rounds
-                    cut = np.arange(len(seg_ids), dtype=np.int32)
-                    parts.append(_take(seg, cut[:room]))
-                    pool[0] = (seg_ids[room:], _take(seg, cut[room:]))
-                    ids += seg_ids[:room]
-                    room = 0
-            n_fresh = min(room, len(pending))
-            spawned: list[int] = []
-            if n_fresh:
-                take, pending = pending[:n_fresh], pending[n_fresh:]
-                parts.append(fresh(take))
-                ids += take
-                spawned = take
-                room -= n_fresh
-            if room:                  # zero-horizon padding: freezes on
-                parts.append(stack_states(pad_template, room))  # entry
-                ids += [-1] * room
-            sb = parts[0] if len(parts) == 1 else _cat(parts)
+                # --- assemble the round's batch: survivors, refill, pad ----
+                parts, ids = [], []
+                room = C
+                while pool and room:
+                    seg_ids, seg = pool[0]
+                    if len(seg_ids) <= room:
+                        pool.pop(0)
+                        parts.append(seg)
+                        ids += seg_ids
+                        room -= len(seg_ids)
+                    else:                 # split a segment across rounds
+                        cut = np.arange(len(seg_ids), dtype=np.int32)
+                        parts.append(_take(seg, cut[:room]))
+                        pool[0] = (seg_ids[room:], _take(seg, cut[room:]))
+                        ids += seg_ids[:room]
+                        room = 0
+                n_fresh = min(room, len(pending))
+                spawned: list[int] = []
+                if n_fresh:
+                    take, pending = pending[:n_fresh], pending[n_fresh:]
+                    parts.append(fresh(take))
+                    ids += take
+                    spawned = take
+                    room -= n_fresh
+                if room:                  # zero-horizon padding: freezes on
+                    parts.append(stack_states(pad_template, room))  # entry
+                    ids += [-1] * room
+                sb = parts[0] if len(parts) == 1 else _cat(parts)
 
-            rows = np.asarray(ids, np.int32)
-            live_row = rows >= 0
-            ridx = np.where(live_row, rows, 0)
-            if C == B and np.array_equal(ridx, np.arange(B)):
-                pb = params_b         # identity round: skip the gather
-            else:
-                pb = _take(params_b, ridx)
-            u_vec = np.where(live_row, u[ridx], 0.0).astype(np.float32)
-            cap = budget[ridx].astype(np.int64) if endgame else \
-                np.minimum(ep[ridx] + schedule.quantum,
-                           budget[ridx].astype(np.int64))
-            m_vec = np.where(live_row, cap, 0).astype(np.int32)
-            b_vec = np.where(live_row, budget[ridx], 0).astype(np.int32)
+                rows = np.asarray(ids, np.int32)
+                live_row = rows >= 0
+                ridx = np.where(live_row, rows, 0)
+                if C == B and np.array_equal(ridx, np.arange(B)):
+                    pb = params_b         # identity round: skip the gather
+                else:
+                    pb = _take(params_b, ridx)
+                u_vec = np.where(live_row, u[ridx], 0.0).astype(np.float32)
+                cap = budget[ridx].astype(np.int64) if endgame else \
+                    np.minimum(ep[ridx] + schedule.quantum,
+                               budget[ridx].astype(np.int64))
+                m_vec = np.where(live_row, cap, 0).astype(np.int32)
+                b_vec = np.where(live_row, budget[ridx], 0).astype(np.int32)
 
-            used_rungs.add(C)
-            tele = BUS.active         # snapshot once per round
-            if tele and d > 1:
-                # global re-pack diagnostics: which mesh slot does each
-                # live config land on this round, vs where it ran last
-                # round — moved lanes are exactly the cross-shard
-                # rebalancing the pmap path couldn't do
-                per_dev = C // d
-                moved = n_live = 0
-                for j, i in enumerate(ids):
-                    if i < 0:
-                        continue
-                    n_live += 1
-                    slot = j // per_dev
-                    if i in shard_of and shard_of[i] != slot:
-                        moved += 1
-                    shard_of[i] = slot
-                BUS.emit("shard.rebalance", round=n_dispatched, shards=d,
-                         moved=moved, lanes=n_live)
-                BUS.count("dse.shard.lanes_moved", moved)
+                used_rungs.add(C)
+                tele = BUS.active         # snapshot once per round
+                if tele and d > 1:
+                    # global re-pack diagnostics: which mesh slot does each
+                    # live config land on this round, vs where it ran last
+                    # round — moved lanes are exactly the cross-shard
+                    # rebalancing the pmap path couldn't do
+                    per_dev = C // d
+                    moved = n_live = 0
+                    for j, i in enumerate(ids):
+                        if i < 0:
+                            continue
+                        n_live += 1
+                        slot = j // per_dev
+                        if i in shard_of and shard_of[i] != slot:
+                            moved += 1
+                        shard_of[i] = slot
+                    BUS.emit("shard.rebalance", round=n_dispatched, shards=d,
+                             moved=moved, lanes=n_live)
+                    BUS.count("dse.shard.lanes_moved", moved)
             t0 = time.perf_counter()
-            out = self.run_batch(sb, pb, u_vec, m_vec, d)
-            pend = self._liveness_start(out, u_vec, b_vec)
+            with BUS.span("round.launch"):
+                out = self.run_batch(sb, pb, u_vec, m_vec, d)
+                pend = self._liveness_start(out, u_vec, b_vec)
             n_dispatched += 1
             return {"ids": ids, "out": out, "pend": pend, "C": C,
                     "rung": rung, "endgame": endgame,
@@ -781,91 +786,95 @@ class BatchRunner:
                     "t_dispatch": t0, "host_s": t0 - h0}
 
         def resolve(rec):
-            """Block on a dispatched round's liveness (the copy has been
-            streaming since dispatch), then harvest finished lanes and
-            compact survivors back into the pool."""
+            """Block on a dispatched round's liveness (``round.wait``,
+            the round's ``wait_s``; the copy has been streaming since
+            dispatch), then harvest finished lanes and compact survivors
+            back into the pool (``round.harvest``, which with the
+            assembly makes the round's ``host_s``)."""
             nonlocal n_rounds, host_accum, wait_accum
-            (live, ep_c), wait_s = self._liveness_read(rec["pend"])
+            with BUS.span("round.wait"):
+                (live, ep_c), wait_s = self._liveness_read(rec["pend"])
             dt = time.perf_counter() - rec["t_dispatch"]
             h0 = time.perf_counter()
-            ids, out, C = rec["ids"], rec["out"], rec["C"]
-            live_row, spawned = rec["live_row"], rec["spawned"]
-            tele = BUS.active
+            with BUS.span("round.harvest"):
+                ids, out, C = rec["ids"], rec["out"], rec["C"]
+                live_row, spawned = rec["live_row"], rec["spawned"]
+                tele = BUS.active
 
-            round_epochs = 0
-            surv_rows, surv_ids = [], []
-            fin_rows, fin_ids = [], []
-            for j, i in enumerate(ids):
-                if i < 0:
-                    continue
+                round_epochs = 0
+                surv_rows, surv_ids = [], []
+                fin_rows, fin_ids = [], []
+                for j, i in enumerate(ids):
+                    if i < 0:
+                        continue
+                    if tele:
+                        round_epochs += int(ep_c[j]) - int(ep[i])
+                    ep[i] = int(ep_c[j])
+                    if live[j]:
+                        surv_rows.append(j)
+                        surv_ids.append(i)
+                    else:
+                        fin_rows.append(j)
+                        fin_ids.append(i)
+                # compaction / harvest: one gather per leaf per group (lane
+                # slicing per config would be ~leaves x lanes dispatches);
+                # a round the whole batch finishes (or survives) needs none
+                if fin_rows:
+                    if len(fin_rows) == C:
+                        done.append((fin_ids, out))
+                    else:
+                        done.append((fin_ids, _take(
+                            out, np.asarray(fin_rows, np.int32))))
+                if surv_rows:
+                    if len(surv_rows) == C:
+                        pool.append((surv_ids, out))
+                    else:
+                        pool.append((surv_ids, _take(
+                            out, np.asarray(surv_rows, np.int32))))
+                host_s = rec["host_s"] + (time.perf_counter() - h0)
+                host_accum += host_s
+                wait_accum += wait_s
+                if tuner is not None:
+                    tuner.record(C, dt, lanes=int(np.sum(live_row)),
+                                 host_dt=host_s)
+                    if tele and C in tuner.rates:
+                        BUS.emit("autotune.probe", rung=C, dur=dt,
+                                 lanes=int(np.sum(live_row)),
+                                 rate=tuner.rates[C])
+                else:
+                    q0 = schedule.quantum
+                    schedule.grow_quantum(dt, host_s, steps=depth)
+                    if tele and schedule.quantum != q0:
+                        BUS.emit("quantum.grow", quantum=schedule.quantum,
+                                 was=q0, round_dur=dt, host_s=host_s)
                 if tele:
-                    round_epochs += int(ep_c[j]) - int(ep[i])
-                ep[i] = int(ep_c[j])
-                if live[j]:
-                    surv_rows.append(j)
-                    surv_ids.append(i)
-                else:
-                    fin_rows.append(j)
-                    fin_ids.append(i)
-            # compaction / harvest: one gather per leaf per group (lane
-            # slicing per config would be ~leaves x lanes dispatches);
-            # a round the whole batch finishes (or survives) needs none
-            if fin_rows:
-                if len(fin_rows) == C:
-                    done.append((fin_ids, out))
-                else:
-                    done.append((fin_ids, _take(
-                        out, np.asarray(fin_rows, np.int32))))
-            if surv_rows:
-                if len(surv_rows) == C:
-                    pool.append((surv_ids, out))
-                else:
-                    pool.append((surv_ids, _take(
-                        out, np.asarray(surv_rows, np.int32))))
-            host_s = rec["host_s"] + (time.perf_counter() - h0)
-            host_accum += host_s
-            wait_accum += wait_s
-            if tuner is not None:
-                tuner.record(C, dt, lanes=int(np.sum(live_row)),
-                             host_dt=host_s)
-                if tele and C in tuner.rates:
-                    BUS.emit("autotune.probe", rung=C, dur=dt,
-                             lanes=int(np.sum(live_row)),
-                             rate=tuner.rates[C])
-            else:
-                q0 = schedule.quantum
-                schedule.grow_quantum(dt, host_s, steps=depth)
-                if tele and schedule.quantum != q0:
-                    BUS.emit("quantum.grow", quantum=schedule.quantum,
-                             was=q0, round_dur=dt, host_s=host_s)
-            if tele:
-                # the per-round heartbeat: lane spawn/freeze/harvest and
-                # the compaction decision, one event per drained round
-                overlap = host_s / max(host_s + wait_s, 1e-9)
-                BUS.emit(
-                    "round.end", round=rec["round"], rung=C, dur=dt,
-                    live=int(np.sum(live_row)), fresh=len(spawned),
-                    pad=int(np.sum(~live_row)), epochs=round_epochs,
-                    finished=len(fin_ids), survivors=len(surv_ids),
-                    pending=len(pending),
-                    pool=sum(len(g) for g, _ in pool),
-                    quantum=schedule.quantum,
-                    endgame=bool(rec["endgame"]),
-                    probe=rec["rung"] is not None,
-                    compacted=bool(surv_rows)
-                    and len(surv_rows) != C,
-                    inflight=len(inflight),
-                    host_s=host_s, wait_s=wait_s,
-                    overlap_frac=overlap,
-                    spawned_ids=spawned[:128],
-                    frozen_ids=fin_ids[:128])
-                BUS.count("dse.rounds")
-                BUS.count("dse.lanes_finished", len(fin_ids))
-                BUS.observe("dse.round_s", dt)
-                BUS.gauge("dse.lanes_live", len(surv_ids))
-                BUS.gauge("dse.lanes_pending", len(pending))
-                BUS.gauge("dse.round.overlap_frac", overlap)
-            n_rounds += 1
+                    # the per-round heartbeat: lane spawn/freeze/harvest and
+                    # the compaction decision, one event per drained round
+                    overlap = host_s / max(host_s + wait_s, 1e-9)
+                    BUS.emit(
+                        "round.end", round=rec["round"], rung=C, dur=dt,
+                        live=int(np.sum(live_row)), fresh=len(spawned),
+                        pad=int(np.sum(~live_row)), epochs=round_epochs,
+                        finished=len(fin_ids), survivors=len(surv_ids),
+                        pending=len(pending),
+                        pool=sum(len(g) for g, _ in pool),
+                        quantum=schedule.quantum,
+                        endgame=bool(rec["endgame"]),
+                        probe=rec["rung"] is not None,
+                        compacted=bool(surv_rows)
+                        and len(surv_rows) != C,
+                        inflight=len(inflight),
+                        host_s=host_s, wait_s=wait_s,
+                        overlap_frac=overlap,
+                        spawned_ids=spawned[:128],
+                        frozen_ids=fin_ids[:128])
+                    BUS.count("dse.rounds")
+                    BUS.count("dse.lanes_finished", len(fin_ids))
+                    BUS.observe("dse.round_s", dt)
+                    BUS.gauge("dse.lanes_live", len(surv_ids))
+                    BUS.gauge("dse.lanes_pending", len(pending))
+                    BUS.gauge("dse.round.overlap_frac", overlap)
+                n_rounds += 1
 
         while pool or pending or inflight:
             # fill the pipeline: dispatch up to ``depth`` rounds before
@@ -894,16 +903,17 @@ class BatchRunner:
                      chunk=schedule.top, quantum=schedule.quantum,
                      shard=d, pipeline=depth, overlap_frac=occ,
                      trace_count=self.trace_count)
-        # final assembly in point order: concat the finished segments
-        # once, then one gather per leaf restores lane order
-        all_ids = np.asarray([i for ids, _ in done for i in ids], np.int32)
-        full = (done[0][1] if len(done) == 1 else
-                _cat([t for _, t in done]))
-        if np.array_equal(all_ids, np.arange(B)):
-            return full               # already in point order
-        pos = np.empty(B, np.int32)
-        pos[all_ids] = np.arange(B, dtype=np.int32)
-        return _take(full, pos)
+        with BUS.span("rounds.final"):
+            # final assembly in point order: concat the finished segments
+            # once, then one gather per leaf restores lane order
+            all_ids = np.asarray([i for ids, _ in done for i in ids], np.int32)
+            full = (done[0][1] if len(done) == 1 else
+                    _cat([t for _, t in done]))
+            if np.array_equal(all_ids, np.arange(B)):
+                return full               # already in point order
+            pos = np.empty(B, np.int32)
+            pos[all_ids] = np.arange(B, dtype=np.int32)
+            return _take(full, pos)
 
 
 # ---------------------------------------------------------------------------
@@ -1099,124 +1109,130 @@ def run_sweep(build_fn: Callable, spec: SweepSpec, until,
         raise ValueError(
             f"resume= must give one handle (or None) per point: "
             f"{len(resume)} != {len(spec)}")
-    dse_cache.ensure_enabled()       # enable-on-first-sweep: wire the
-    # persistent jax compilation cache when a campaign dir is configured
-    rows: list[dict | None] = [None] * len(spec)
-    lane_states = LaneStates() if return_states else None
-    until_arr = np.broadcast_to(np.asarray(until, np.float32), (len(spec),))
-    me_arr = np.broadcast_to(np.asarray(max_epochs, np.int64), (len(spec),))
-    shape_mode = spec.has_shape_axes()
-    tele = BUS.active
-    sweep_t0 = time.perf_counter()
-    if tele:
-        BUS.emit("sweep.start", n_points=len(spec), axes=spec.summary(),
-                 shape_mode=bool(shape_mode), shard=_shard_devices(shard),
-                 warm=(0 if resume is None
-                       else sum(1 for h in resume if h is not None)))
-        BUS.count("dse.sweeps")
-    static_ok = _static_kwarg_names(build_fn)
-    if static_ok is not None:
-        bad = [a for a in spec.axes if a.startswith(STATIC_PREFIX)
-               and a[len(STATIC_PREFIX):] not in static_ok]
-        if bad:
-            raise ValueError(
-                f"invalid static axes {bad}: build function accepts "
-                f"only {sorted(static_ok)}")
-    group_no = 0
-    for static_kwargs, indices, traced in spec.split_static():
+    with BUS.span("sweep"):
+        dse_cache.ensure_enabled()       # enable-on-first-sweep: wire the
+        # persistent jax compilation cache when a campaign dir is configured
+        rows: list[dict | None] = [None] * len(spec)
+        lane_states = LaneStates() if return_states else None
+        until_arr = np.broadcast_to(np.asarray(until, np.float32),
+                                    (len(spec),))
+        me_arr = np.broadcast_to(np.asarray(max_epochs, np.int64),
+                                 (len(spec),))
+        shape_mode = spec.has_shape_axes()
+        tele = BUS.active
+        sweep_t0 = time.perf_counter()
         if tele:
-            BUS.emit("sweep.group", group=group_no,
-                     static={k: str(v) for k, v in static_kwargs.items()},
-                     n_points=len(indices), family=bool(shape_mode))
-        group_no += 1
-        # validate each group's own axes against that group's build (a
-        # group's sim can differ structurally, e.g. static.n_cores, so
-        # neither the whole-spec union nor a single target would do)
-        group_spec = SweepSpec(tuple(traced))
-        u_group = until_arr[np.asarray(indices)]
-        me_group = me_arr[np.asarray(indices)]
-        res = ([resume[i] for i in indices] if resume is not None
-               else None)
-        warm = res is not None and any(h is not None for h in res)
-        init_ep = (np.asarray([int(h.epochs) if h is not None else 0
-                               for h in res], np.int64) if warm else None)
-        sched = auto_schedule(len(indices), chunk=chunk) \
-            if schedule is None and chunk is not None else schedule
-        if shape_mode:
-            split = [split_shape(pt) for pt in traced]
-            fam_shape: dict[str, int] = {}
-            for shape_pt, _ in split:
-                for name, v in shape_pt.items():
-                    fam_shape[name] = max(int(v), fam_shape.get(name, 1))
-            fam = build_fn(**static_kwargs, shape=fam_shape)
-            if not isinstance(fam, TopologyFamily):
-                raise TypeError(
-                    "shape.* axes require a family-aware build function: "
-                    "build_fn(**static, shape={...}) must return a "
-                    f"TopologyFamily, got {type(fam).__name__}")
-            group_spec.validate(fam)
-            sim = fam.sim
-            base = sim.default_params()
-            # grids repeat shapes across traced-axis combinations: derive
-            # each distinct shape's masks once and share them between the
-            # lane's params and initial state
-            mask_cache: dict[tuple, tuple] = {}
-            plist, states = [], []
-            for shape_pt, traced_pt in split:
-                full = fam.full_shape(shape_pt)
-                key = tuple(sorted(full.items()))
-                if key not in mask_cache:
-                    mask_cache[key] = fam.masks(full)
-                m = mask_cache[key]
-                plist.append(fam.params_for(
-                    full, apply_point(base, traced_pt), masks=m))
-                states.append(fam.state_for(full, masks=m))
-            if warm:                # handled lanes continue, not restart
-                states = [h.state if h is not None else s
-                          for h, s in zip(res, states)]
-            params_b = stack_params(plist)
-            runner = runner_for(sim)
-            out = runner.run_rounds(states, params_b, u_group,
-                                    schedule=sched, max_epochs=me_group,
-                                    shard=shard, init_epochs=init_ep,
-                                    pipeline=pipeline)
-        else:
-            sim, st = build_fn(**static_kwargs)
-            group_spec.validate(sim)
-            params_b = build_param_batch(sim, traced)
-            runner = runner_for(sim)
-            template = ([h.state if h is not None else st for h in res]
-                        if warm else st)
-            out = runner.run_rounds(template, params_b, u_group,
-                                    schedule=sched, max_epochs=me_group,
-                                    shard=shard, init_epochs=init_ep,
-                                    pipeline=pipeline)
-        # one device_get serves both the result rows and (when asked)
-        # the resumable final states — never two transfers per group
-        ex = extract or default_extract
-        t0 = time.perf_counter()
-        host = jax.device_get(out)
+            BUS.emit("sweep.start", n_points=len(spec), axes=spec.summary(),
+                     shape_mode=bool(shape_mode), shard=_shard_devices(shard),
+                     warm=(0 if resume is None
+                           else sum(1 for h in resume if h is not None)))
+            BUS.count("dse.sweeps")
+        static_ok = _static_kwarg_names(build_fn)
+        if static_ok is not None:
+            bad = [a for a in spec.axes if a.startswith(STATIC_PREFIX)
+                   and a[len(STATIC_PREFIX):] not in static_ok]
+            if bad:
+                raise ValueError(
+                    f"invalid static axes {bad}: build function accepts "
+                    f"only {sorted(static_ok)}")
+        group_no = 0
+        for static_kwargs, indices, traced in spec.split_static():
+            if tele:
+                BUS.emit("sweep.group", group=group_no,
+                         static={k: str(v) for k, v in static_kwargs.items()},
+                         n_points=len(indices), family=bool(shape_mode))
+            group_no += 1
+            # validate each group's own axes against that group's build (a
+            # group's sim can differ structurally, e.g. static.n_cores, so
+            # neither the whole-spec union nor a single target would do)
+            group_spec = SweepSpec(tuple(traced))
+            u_group = until_arr[np.asarray(indices)]
+            me_group = me_arr[np.asarray(indices)]
+            res = ([resume[i] for i in indices] if resume is not None
+                   else None)
+            warm = res is not None and any(h is not None for h in res)
+            init_ep = (np.asarray([int(h.epochs) if h is not None else 0
+                                   for h in res], np.int64) if warm else None)
+            sched = auto_schedule(len(indices), chunk=chunk) \
+                if schedule is None and chunk is not None else schedule
+            if shape_mode:
+                split = [split_shape(pt) for pt in traced]
+                fam_shape: dict[str, int] = {}
+                for shape_pt, _ in split:
+                    for name, v in shape_pt.items():
+                        fam_shape[name] = max(int(v), fam_shape.get(name, 1))
+                with BUS.span("sweep.build"):
+                    fam = build_fn(**static_kwargs, shape=fam_shape)
+                    if not isinstance(fam, TopologyFamily):
+                        raise TypeError(
+                            "shape.* axes require a family-aware build "
+                            "function: build_fn(**static, shape={...}) "
+                            "must return a TopologyFamily, got "
+                            f"{type(fam).__name__}")
+                    group_spec.validate(fam)
+                sim = fam.sim
+                with BUS.span("sweep.params"):
+                    base = sim.default_params()
+                    # grids repeat shapes across traced-axis combinations:
+                    # derive each distinct shape's masks once and share them
+                    # between the lane's params and initial state
+                    mask_cache: dict[tuple, tuple] = {}
+                    plist, template = [], []
+                    for shape_pt, traced_pt in split:
+                        full = fam.full_shape(shape_pt)
+                        key = tuple(sorted(full.items()))
+                        if key not in mask_cache:
+                            mask_cache[key] = fam.masks(full)
+                        m = mask_cache[key]
+                        plist.append(fam.params_for(
+                            full, apply_point(base, traced_pt), masks=m))
+                        template.append(fam.state_for(full, masks=m))
+                    if warm:            # handled lanes continue, not restart
+                        template = [h.state if h is not None else s
+                                    for h, s in zip(res, template)]
+                    params_b = stack_params(plist)
+            else:
+                with BUS.span("sweep.build"):
+                    sim, st = build_fn(**static_kwargs)
+                    group_spec.validate(sim)
+                with BUS.span("sweep.params"):
+                    params_b = build_param_batch(sim, traced)
+                template = ([h.state if h is not None else st for h in res]
+                            if warm else st)
+            with BUS.span("sweep.rounds"):
+                out = runner_for(sim).run_rounds(
+                    template, params_b, u_group, schedule=sched,
+                    max_epochs=me_group, shard=shard, init_epochs=init_ep,
+                    pipeline=pipeline)
+            # one device_get serves both the result rows and (when asked)
+            # the resumable final states — never two transfers per group
+            with BUS.span("sweep.transfer"):
+                t0 = time.perf_counter()
+                host = jax.device_get(out)
+                if tele:
+                    dt = time.perf_counter() - t0
+                    BUS.emit("transfer", what="rows", lanes=len(indices),
+                             dur=dt, bytes=int(sum(
+                                 x.nbytes for x in jax.tree.leaves(host)
+                                 if hasattr(x, "nbytes"))))
+                    BUS.observe("dse.transfer.rows_s", dt)
+            with BUS.span("sweep.extract"):
+                ex = extract or default_extract
+                if _extract_arity(ex) >= 3:     # index-aware: mux row routing
+                    group_rows = [ex(sim, lane(host, j), indices[j])
+                                  for j in range(len(indices))]
+                else:
+                    group_rows = [ex(sim, lane(host, j))
+                                  for j in range(len(indices))]
+                if lane_states is not None:
+                    lane_states.add_group(host, indices)
+                for j, i in enumerate(indices):
+                    row = dict(spec.points[i])
+                    row.update(group_rows[j])
+                    rows[i] = row
         if tele:
-            dt = time.perf_counter() - t0
-            BUS.emit("transfer", what="rows", lanes=len(indices), dur=dt,
-                     bytes=int(sum(x.nbytes for x in jax.tree.leaves(host)
-                                   if hasattr(x, "nbytes"))))
-            BUS.observe("dse.transfer.rows_s", dt)
-        if _extract_arity(ex) >= 3:     # index-aware: mux row routing
-            group_rows = [ex(sim, lane(host, j), indices[j])
-                          for j in range(len(indices))]
-        else:
-            group_rows = [ex(sim, lane(host, j))
-                          for j in range(len(indices))]
-        if lane_states is not None:
-            lane_states.add_group(host, indices)
-        for j, i in enumerate(indices):
-            row = dict(spec.points[i])
-            row.update(group_rows[j])
-            rows[i] = row
-    if tele:
-        BUS.emit("sweep.end", n_points=len(spec), groups=group_no,
-                 dur=time.perf_counter() - sweep_t0)
+            BUS.emit("sweep.end", n_points=len(spec), groups=group_no,
+                     dur=time.perf_counter() - sweep_t0)
     if return_states:
         return list(rows), lane_states
     return list(rows)

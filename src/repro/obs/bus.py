@@ -28,6 +28,10 @@ Design constraints, in order:
   fields; completed spans carry ``dur`` (seconds).  The schema version
   (:data:`SCHEMA_VERSION`) rides the JSONL header and the event
   catalogue lives in OBSERVABILITY.md.
+* **Spans on the profiler's clock too.**  :meth:`Bus.span` always opens
+  a ``jax.profiler.TraceAnnotation`` under the span's plain name, so a
+  profiler trace shows the program's phases beside the device's
+  operations whether or not a sink is attached.
 
 Sinks implement a single method ``on_event(ev: dict)`` (and optionally
 ``close()``); a sink that raises is detached-in-place semantics-free —
@@ -40,6 +44,8 @@ import contextlib
 import threading
 import time
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 SCHEMA_VERSION = 1
 
@@ -202,16 +208,22 @@ class Bus:
 
     @contextlib.contextmanager
     def span(self, kind: str, **fields):
-        """Emit ``kind`` as a completed span on exit (``dur`` = wall
-        seconds inside the block).  Payload fields may be added by
-        mutating the yielded dict."""
+        """A program phase on two clocks: a profiler span named ``kind``
+        (``jax.profiler.TraceAnnotation``, the plain name; one TraceMe
+        enter and exit when no profiler runs) and, while a sink is
+        attached at exit, ``kind`` emitted as a completed bus event
+        (``dur`` = wall seconds inside the block).  Payload fields may
+        be added by mutating the yielded dict; they go to the bus event
+        only.  Open spans at phase granularity, never per point, lane or
+        epoch."""
         extra: dict = dict(fields)
         t0 = time.perf_counter()
-        try:
-            yield extra
-        finally:
-            if self._sinks:
-                self.emit(kind, dur=time.perf_counter() - t0, **extra)
+        with TraceAnnotation(kind):
+            try:
+                yield extra
+            finally:
+                if self._sinks:
+                    self.emit(kind, dur=time.perf_counter() - t0, **extra)
 
     # -- metric sugar (guarded: no-ops while inactive) ----------------------
     def count(self, name: str, n: float = 1.0) -> None:

@@ -189,3 +189,62 @@ def test_search_disabled_is_silent_and_identical(ctx):
     assert r_off.best == r_on.best
     assert r_off.budget == r_on.budget
     _rows_equal(r_off.rows, r_on.rows)
+
+
+# ---------------------------------------------------------------------------
+SWEEP_SPANS = ("sweep", "sweep.build", "sweep.params", "sweep.rounds",
+               "sweep.transfer", "sweep.extract")
+ROUND_SPANS = ("round.assemble", "round.launch", "round.wait",
+               "round.harvest", "rounds.final")
+
+
+def _host_spans(logdir):
+    """``(start, end, name)`` of the program's spans in the newest
+    profiler trace under ``logdir``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    names = set(SWEEP_SPANS + ROUND_SPANS)
+    return [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name in names]
+
+
+def test_sweep_spans_reach_the_profiler_and_the_bus(ctx, tmp_path):
+    """Every sweep and round phase is a profiler span under its plain
+    name, nested as the code nests them, and a bus event while a sink
+    is attached; with no sink the spans materialize no event."""
+    import jax
+    bf, extract, pool = ctx
+    spec = SweepSpec.grid({"conn_latency[-1]": [10., 20., 30.]})
+    kw = dict(until=300.0, extract=extract, chunk=2)
+    run_sweep(bf, spec, **kw)                 # compile outside the trace
+    seq0 = BUS.seq
+    with jax.profiler.trace(str(tmp_path)):
+        run_sweep(bf, spec, **kw)
+    assert BUS.seq == seq0
+    spans = _host_spans(str(tmp_path))
+    assert {n for _, _, n in spans} == set(SWEEP_SPANS + ROUND_SPANS)
+    (outer,) = [(s, e) for s, e, n in spans if n == "sweep"]
+    (rounds,) = [(s, e) for s, e, n in spans if n == "sweep.rounds"]
+    for s, e, n in spans:
+        assert outer[0] <= s <= e <= outer[1], n
+        if n in ROUND_SPANS:
+            assert rounds[0] <= s <= e <= rounds[1], n
+    # the phases of one level follow one another without overlapping
+    for level in (SWEEP_SPANS[1:], ROUND_SPANS):
+        iv = sorted((s, e) for s, e, n in spans if n in level)
+        assert all(a[1] <= b[0] for a, b in zip(iv, iv[1:]))
+
+    with capture() as sink:
+        run_sweep(bf, spec, **kw)
+    kinds = sink.kinds()
+    for name in SWEEP_SPANS + ROUND_SPANS:
+        assert name in kinds, name
+        assert all(ev["dur"] >= 0.0 for ev in sink.of(name))
+    assert kinds.count("sweep") == 1
+    assert kinds.count("round.launch") == len(sink.of("round.end"))

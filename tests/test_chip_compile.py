@@ -7,7 +7,9 @@ The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library, and pytest-xdist
 workers must all collect the same tests.
 """
+import contextlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -93,3 +95,86 @@ def test_sharded_round_program_compiles_on_four_chips(topo, monkeypatch):
                               NamedSharding(mesh, P(LANE_AXIS)))).compile()
     _fits(compiled)
     assert compiled.as_text().count("all-reduce") == 0   # lanes independent
+
+
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+# the source-location tables at the top of an HLO module's text
+_LOCATION_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames")
+
+
+def _without_metadata(hlo: str) -> str:
+    """An HLO module's text without its op metadata and source-location
+    tables, its instructions renamed in order of first use (the
+    lowering numbers names after the op names that scopes extend)."""
+    out, skip = [], False
+    for line in hlo.split("\n"):
+        if line in _LOCATION_TABLES:
+            skip = True
+        elif skip and line[:1] in ("%", "E"):    # computations resume
+            skip = False
+        if not skip:
+            out.append(_METADATA.sub("", line))
+    names: dict = {}
+    return re.sub(r"%[\w.-]+",
+                  lambda m: names.setdefault(m[0], f"%v{len(names)}"),
+                  "\n".join(out))
+
+
+def _compiled_with_and_without_scopes(compile_fn, monkeypatch):
+    """The optimized HLO of ``compile_fn()`` as the engine's named scopes
+    leave it, and with every ``jax.named_scope`` a no-op."""
+    scoped = compile_fn()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        bare = compile_fn()
+    return scoped, bare
+
+
+def _scopes(hlo: str) -> set:
+    return set(re.findall(r'op_name="[^"]*?(engine\.[A-Za-z0-9_.]+)', hlo))
+
+
+def _assert_scopes_only_name(scoped, bare, kinds):
+    assert _without_metadata(scoped) == _without_metadata(bare)
+    names = _scopes(scoped)
+    assert {"engine.next_event", "engine.deliver", "engine.update"} <= names
+    assert {f"engine.tick.{k}" for k in kinds} <= names
+    assert not _scopes(bare)
+
+
+def test_named_scopes_leave_the_engine_loop_unchanged(topo, monkeypatch):
+    from repro.sims.memsys import build
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def compile_run():
+        sim, st = build(n_cores=16, pattern="mixed", n_reqs=16)
+        args = _sds((st, np.float32(1e6), np.int32(1_000_000)), one)
+        return sim._run_jit.lower(*args).compile().as_text()
+
+    _assert_scopes_only_name(
+        *_compiled_with_and_without_scopes(compile_run, monkeypatch),
+        ("core", "l1", "dram"))
+
+
+def test_named_scopes_leave_the_round_program_unchanged(topo, monkeypatch):
+    from repro.dse import BatchRunner, build_param_batch, stack_states
+    from repro.sims.onira import MICROBENCHES, build_onira
+    one = SingleDeviceSharding(topo.devices[0])
+    progs = [np.asarray(MICROBENCHES[n]()) for n in ("ALU", "ST_LD")]
+    width = max(len(p) for p in progs)
+    progs = [np.pad(p, ((0, width - len(p)), (0, 0))) for p in progs]
+
+    def compile_round():
+        sim, st = build_onira(progs)
+        pts = [{"conn_latency": 1.0 + i, "kind.cpu.flush_cycles": 2.0}
+               for i in range(8)]
+        states = jax.eval_shape(lambda s: stack_states(s, 8), st)
+        lanes = np.zeros(8, np.float32), np.zeros(8, np.int32)
+        fn = BatchRunner(sim)._batched_fn(8, 1)
+        args = _sds((states, build_param_batch(sim, pts), *lanes), one)
+        return fn.lower(*args).compile().as_text()
+
+    _assert_scopes_only_name(
+        *_compiled_with_and_without_scopes(compile_round, monkeypatch),
+        ("cpu", "mem"))
