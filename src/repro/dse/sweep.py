@@ -38,14 +38,17 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import re
+import time
 import zlib
 from typing import Any, Iterable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src.lax import lax as _lax
 
 from repro.core import SimParams
+from repro.obs.bus import BUS
 
 STATIC_PREFIX = "static."
 SHAPE_PREFIX = "shape."
@@ -314,107 +317,103 @@ def valid_axes(params: SimParams, shape_axes=None) -> list[str]:
     return axes
 
 
+def _resolve(params: SimParams, path: str) -> tuple[tuple, int | None]:
+    """The one parser of a traced axis path, shared by :func:`axis_error`,
+    :func:`apply_point` and :func:`build_param_batch`.
+
+    Returns the leaf the path names as a key path into ``params``
+    (``("conn_latency",)``, ``("periods", kind)`` or ``("kind", kind,
+    *leaf)``) and its element index (``None`` for the whole leaf).
+    Unknown, ``static.*`` and ``shape.*`` paths raise ``KeyError``; an
+    index out of range raises ``AssertionError``.
+    """
+    if path.startswith(STATIC_PREFIX):
+        raise KeyError(f"static axis {path!r} reached apply_point — "
+                       "route points through SweepSpec.split_static")
+    if path.startswith(SHAPE_PREFIX):
+        raise KeyError(f"shape axis {path!r} reached apply_point — "
+                       "route points through split_shape and a "
+                       "TopologyFamily (masks, not param leaves)")
+    m = _INDEXED.match(path)
+    base, ix = (m["base"], int(m["ix"])) if m else (path, None)
+    if base == "conn_latency":
+        key = ("conn_latency",)
+    elif base.startswith("period."):
+        kname = base[len("period."):]
+        if kname not in params.periods:
+            raise KeyError(f"{path!r}: unknown kind {kname!r} "
+                           f"(have {sorted(params.periods)})")
+        key = ("periods", kname)
+    elif base.startswith("kind."):
+        if ix is not None:
+            raise KeyError(f"{path!r}: kind-param axes are not indexable")
+        kname, _, leaf = base[len("kind."):].partition(".")
+        if kname not in params.kind or not params.kind[kname]:
+            raise KeyError(f"{path!r}: kind {kname!r} has no params "
+                           f"(kinds with params: "
+                           f"{sorted(k for k, v in params.kind.items() if v)})")
+        tree = params.kind[kname]
+        for k in leaf.split("."):
+            tree = tree.get(k) if isinstance(tree, dict) else None
+        if tree is None or isinstance(tree, dict):
+            raise KeyError(f"{path!r}: no param leaf {leaf!r} on kind "
+                           f"{kname!r} "
+                           f"(have {_leaf_paths(params.kind[kname])})")
+        return ("kind", kname, *leaf.split(".")), None
+    else:
+        raise KeyError(f"unknown sweep axis {path!r}")
+    if ix is not None:
+        n = _leaf(params, key).shape[0]
+        if not -n <= ix < n:
+            raise AssertionError(f"{path!r}: index {ix} out of range "
+                                 f"for [{n}]")
+    return key, ix
+
+
+def _leaf(params: SimParams, key: tuple):
+    node = getattr(params, key[0])
+    for k in key[1:]:
+        node = node[k]
+    return node
+
+
+def _with_leaf(params: SimParams, key: tuple, value) -> SimParams:
+    def put(node, keys):
+        if not keys:
+            return value
+        return {**node, keys[0]: put(node[keys[0]], keys[1:])}
+    return dataclasses.replace(
+        params, **{key[0]: put(getattr(params, key[0]), key[1:])})
+
+
 def axis_error(params: SimParams, path: str) -> str | None:
     """``None`` if ``path`` names a traced leaf of ``params``, else a
     one-line description of why it does not."""
-    m = _INDEXED.match(path)
-    base, ix = (m["base"], int(m["ix"])) if m else (path, None)
-
-    def ix_ok(n):
-        if ix is not None and not -n <= ix < n:
-            return f"{path!r}: index {ix} out of range for [{n}]"
-        return None
-
-    if base == "conn_latency":
-        return ix_ok(params.conn_latency.shape[0])
-    if base.startswith("period."):
-        kname = base[len("period."):]
-        if kname not in params.periods:
-            return (f"{path!r}: unknown kind {kname!r} "
-                    f"(have {sorted(params.periods)})")
-        return ix_ok(params.periods[kname].shape[0])
-    if base.startswith("kind."):
-        if ix is not None:
-            return f"{path!r}: kind-param axes are not indexable"
-        kname, _, leaf = base[len("kind."):].partition(".")
-        if kname not in params.kind or not params.kind[kname]:
-            return (f"{path!r}: kind {kname!r} has no params "
-                    f"(kinds with params: "
-                    f"{sorted(k for k, v in params.kind.items() if v)})")
-        tree = params.kind[kname]
-        for key in leaf.split("."):
-            if not isinstance(tree, dict) or key not in tree:
-                return (f"{path!r}: no param leaf {leaf!r} on kind "
-                        f"{kname!r} (have {_leaf_paths(params.kind[kname])})")
-            tree = tree[key]
-        return None
-    return f"unknown sweep axis {path!r}"
-
-
-def _set_indexed(arr, path, ix, value):
-    n = arr.shape[0]
-    assert -n <= ix < n, f"{path}: index {ix} out of range for [{n}]"
-    return arr.at[ix].set(jnp.asarray(value, arr.dtype))
+    try:
+        _resolve(params, path)
+    except (KeyError, AssertionError) as e:
+        return e.args[0]
+    return None
 
 
 def apply_point(params: SimParams, point: dict) -> SimParams:
     """Return ``params`` with one design point's traced assignments applied.
 
-    Runs at trace-free build time (plain ``.at`` updates on tiny arrays);
-    unknown paths raise ``KeyError`` so typos fail loudly before compile.
+    The single-point form of :func:`build_param_batch` (eager ``.at``
+    updates on tiny arrays); unknown paths raise ``KeyError`` so typos
+    fail loudly before compile.
     """
-    conn = params.conn_latency
-    periods = dict(params.periods)
-    kind = {k: v for k, v in params.kind.items()}
     for path, value in point.items():
-        if path.startswith(STATIC_PREFIX):
-            raise KeyError(f"static axis {path!r} reached apply_point — "
-                           "route points through SweepSpec.split_static")
-        if path.startswith(SHAPE_PREFIX):
-            raise KeyError(f"shape axis {path!r} reached apply_point — "
-                           "route points through split_shape and a "
-                           "TopologyFamily (masks, not param leaves)")
-        m = _INDEXED.match(path)
-        base, ix = (m["base"], int(m["ix"])) if m else (path, None)
-        if base == "conn_latency":
-            if ix is None:
-                conn = jnp.full_like(conn, float(value))
-            else:
-                conn = _set_indexed(conn, path, ix, value)
-        elif base.startswith("period."):
-            kname = base[len("period."):]
-            if kname not in periods:
-                raise KeyError(f"{path!r}: unknown kind {kname!r} "
-                               f"(have {sorted(periods)})")
-            if ix is None:
-                periods[kname] = jnp.full_like(periods[kname], float(value))
-            else:
-                periods[kname] = _set_indexed(periods[kname], path, ix, value)
-        elif base.startswith("kind."):
-            kname, _, leaf_path = base[len("kind."):].partition(".")
-            if kname not in kind or not leaf_path:
-                raise KeyError(f"{path!r}: unknown kind-param path "
-                               f"(kinds with params: "
-                               f"{sorted(k for k, v in kind.items() if v)})")
-            kind[kname] = _set_leaf(kind[kname], leaf_path.split("."),
-                                    value, path)
+        key, ix = _resolve(params, path)
+        x = _leaf(params, key)
+        if key[0] == "kind":
+            x = jnp.asarray(value, jnp.asarray(x).dtype)
+        elif ix is None:
+            x = jnp.full_like(x, float(value))
         else:
-            raise KeyError(f"unknown sweep axis {path!r}")
-    return dataclasses.replace(params, conn_latency=conn, periods=periods,
-                               kind=kind)
-
-
-def _set_leaf(tree, keys, value, path):
-    if not isinstance(tree, dict) or keys[0] not in tree:
-        raise KeyError(f"{path!r}: no param leaf {'.'.join(keys)!r} "
-                       f"(have {sorted(tree) if isinstance(tree, dict) else tree})")
-    out = dict(tree)
-    if len(keys) == 1:
-        old = out[keys[0]]
-        out[keys[0]] = jnp.asarray(value, jnp.asarray(old).dtype)
-    else:
-        out[keys[0]] = _set_leaf(out[keys[0]], keys[1:], value, path)
-    return out
+            x = x.at[ix].set(jnp.asarray(value, x.dtype))
+        params = _with_leaf(params, key, x)
+    return params
 
 
 def stack_trees(trees: Sequence) -> Any:
@@ -430,6 +429,52 @@ def stack_params(plist: Sequence[SimParams]) -> SimParams:
 
 
 def build_param_batch(sim, points: Sequence[dict]) -> SimParams:
-    """``sim.default_params()`` + each point's assignments, stacked."""
+    """``sim.default_params()`` + each point's assignments, stacked.
+
+    Assembled in NumPy on the host and sent to the default device in one
+    ``jax.device_put``: no device op per point.  Values, shapes, dtypes
+    and ``weak_type`` equal ``stack_params([apply_point(base, p) for p in
+    points])`` leaf for leaf, so the runner's executables match.  Every
+    point is checked (:func:`_resolve`) before anything is transferred.
+    """
+    assert points, "empty batch"
     base = sim.default_params()
-    return stack_params([apply_point(base, pt) for pt in points])
+    host = jax.device_get(base)
+    weak = jax.tree.map(lambda x: bool(getattr(x, "weak_type", False)),
+                        base)
+    b = len(points)
+    batch = jax.tree.map(
+        lambda x: np.broadcast_to(x, (b,) + x.shape).copy(), host)
+    # jnp.full_like converts a whole-leaf fill through the canonical
+    # float dtype (float32 with x64 off) before the leaf's own dtype
+    fill_dtype = jax.dtypes.canonicalize_dtype(np.float64)
+    resolved: dict[str, tuple] = {}
+    retyped = set()
+    for row, point in enumerate(points):
+        for path, value in point.items():
+            if path not in resolved:
+                resolved[path] = _resolve(host, path)
+            key, ix = resolved[path]
+            buf = _leaf(batch, key)
+            if key[0] == "kind":
+                buf[row] = np.asarray(value, buf.dtype)
+                retyped.add(key)
+            elif ix is None:
+                buf[row] = np.asarray(float(value), fill_dtype)
+            else:
+                buf[row, ix] = np.asarray(value, buf.dtype)
+    for key in retyped:                 # jnp.asarray(value, dtype): strong
+        weak = _with_leaf(weak, key, False)
+    tele = BUS.active
+    t0 = time.perf_counter()
+    out = jax.device_put(batch)
+    if tele:
+        dt = time.perf_counter() - t0
+        BUS.emit("transfer", what="params", lanes=b, dur=dt,
+                 bytes=int(sum(x.nbytes for x in jax.tree.leaves(batch))))
+        BUS.observe("dse.transfer.params_s", dt)
+    # a weakly typed default leaf that no point retyped stays weak, as
+    # jnp.stack keeps it; a host buffer cannot carry the flag
+    return jax.tree.map(
+        lambda x, w: _lax._convert_element_type(x, weak_type=True) if w
+        else x, out, weak)

@@ -243,3 +243,141 @@ def test_stack_params_shapes(sim):
         np.asarray(pb.conn_latency[:, -1]), [10.0, 20.0, 40.0])
     # non-swept leaves are identical across the batch
     assert np.all(np.asarray(pb.kind["l1"]["extra_hit_rate"]) == 0.0)
+
+
+# ---------------------------------------------------------------------------
+# build_param_batch: host assembly, one transfer, same batch as the eager
+# composition of apply_point and stack_params
+# ---------------------------------------------------------------------------
+class _HandParams:
+    """A target whose defaults hold what no shipped model does: int32
+    leaves, weakly typed ones and a nested kind-param tree."""
+
+    def default_params(self):
+        from repro.core import SimParams
+        return SimParams(
+            conn_latency=jnp.asarray(np.arange(1.0, 4.0, dtype=np.float32)),
+            periods={"a": jnp.ones(2, jnp.int32)},
+            kind={"a": {"n": jnp.asarray(3),             # weak int32
+                        "w": jnp.asarray(1.0)},          # weak float32
+                  "b": {"x": {"y": jnp.asarray(0.5)}},   # weak, nested
+                  "c": {}})
+
+
+def _target(name):
+    if name == "memsys":
+        return build(n_cores=2, pattern="mixed", n_reqs=4, donate=False)[0]
+    if name == "onira":
+        from repro.sims import onira
+        progs = [onira.MICROBENCHES[k]() for k in onira.MICROBENCHES]
+        return onira.build_onira(progs)[0]
+    return _HandParams()
+
+
+def _every_form(sim, b):
+    """``b`` points that cover every traced path form: whole leaf,
+    indexed from either end, ``period.<k>`` whole and indexed,
+    ``kind.<k>.<leaf>``, a fill then an indexed override in one point,
+    an override then a fill, empty points, and int, np.int64 and float
+    values.  Point 0 holds every form at once."""
+    from repro.dse import valid_axes
+    params = sim.default_params()
+    pk = sorted(params.periods)[0]
+    kleaf = next(a for a in valid_axes(params) if a.startswith("kind."))
+    forms = [
+        lambda r: {"conn_latency": 4, "conn_latency[-1]": 1.5 + r,
+                   f"period.{pk}": 16777217.0, f"period.{pk}[0]": 3.25,
+                   kleaf: 2.7, "conn_latency[0]": np.int64(6)},
+        lambda r: {},
+        lambda r: {"conn_latency": 1 + r % 9},
+        lambda r: {"conn_latency[0]": np.int64(2 + r % 4)},
+        lambda r: {"conn_latency[-1]": 1.5 + 0.1 * (r % 11)},
+        lambda r: {f"period.{pk}": 1.0 + 0.3 * (r % 3)},
+        lambda r: {f"period.{pk}": np.int64(2 + r % 3)},
+        lambda r: {f"period.{pk}[-1]": 2 + r % 2},
+        lambda r: {kleaf: 0.1 * (r % 9)},
+        lambda r: {kleaf: r % 4},
+        lambda r: {kleaf: np.int64(r % 5)},
+        lambda r: {"conn_latency[0]": 9.0, "conn_latency": 0.7 + r},
+    ]
+    return [forms[r % len(forms)](r) for r in range(b)]
+
+
+@pytest.mark.parametrize("b", [1, 257])
+@pytest.mark.parametrize("target", ["memsys", "onira", "hand"])
+def test_build_param_batch_equals_eager_composition(target, b):
+    sim = _target(target)
+    pts = _every_form(sim, b)
+    got = build_param_batch(sim, pts)
+    base = sim.default_params()
+    want = stack_params([apply_point(base, p) for p in pts])
+    got_l, got_def = jax.tree_util.tree_flatten_with_path(got)
+    want_l, want_def = jax.tree_util.tree_flatten_with_path(want)
+    assert got_def == want_def
+    for (path, g), (_, w) in zip(got_l, want_l):
+        where = jax.tree_util.keystr(path)
+        assert isinstance(g, jax.Array), where
+        assert (g.shape, g.dtype, g.weak_type) == \
+            (w.shape, w.dtype, w.weak_type), where
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes(), where
+    if target == "hand":        # the flags the comparison must carry
+        assert got.kind["a"]["w"].weak_type
+        assert not got.kind["a"]["n"].weak_type         # retyped by writes
+        assert got.kind["a"]["n"].dtype == jnp.int32
+        assert got.periods["a"].dtype == jnp.int32
+
+
+@pytest.mark.parametrize("bad", [
+    {"nope": 1.0},
+    {"period.nokind": 1.0},
+    {"kind.l1.nope": 1.0},
+    {"kind.nokind.x": 1.0},
+    {"static.super_epoch": 2},
+    {"shape.core": 2},
+    {"conn_latency[99]": 2.0},
+    {"kind.l1.extra_hit_rate[0]": 0.5},     # kind params take no index
+])
+def test_build_param_batch_raises_what_apply_point_raises(sim, bad):
+    from repro.obs import capture
+    with pytest.raises((KeyError, AssertionError)) as one:
+        apply_point(sim.default_params(), bad)
+    pts = [{"conn_latency": 2.0}, {"conn_latency[-1]": 3.0}, bad,
+           {"kind.l1.extra_hit_rate": 0.5}]
+    with capture() as sink:
+        with pytest.raises(type(one.value)) as batch:
+            build_param_batch(sim, pts)
+    assert type(batch.value) is type(one.value)
+    assert batch.value.args == one.value.args
+    assert sink.of("transfer") == []        # checked before any transfer
+
+
+def test_run_sweep_sends_params_once_per_group_and_never_retraces():
+    from repro.dse import memoize_build, run_sweep, runner_for
+    from repro.obs import BUS, capture
+
+    bf = memoize_build(lambda n_cores: build(
+        n_cores=n_cores, pattern="mixed", n_reqs=4, donate=False))
+    spec = SweepSpec.explicit(
+        [{"static.n_cores": 2, "conn_latency[-1]": float(v)}
+         for v in (5, 10, 20)]
+        + [{"static.n_cores": 3, "conn_latency[-1]": float(v)}
+           for v in (5, 10)])
+    kw = dict(until=400.0, chunk=4)
+    rows = run_sweep(bf, spec, **kw)                    # warm
+    sims = {n: bf(n_cores=n)[0] for n in (2, 3)}
+    tc0 = {n: runner_for(s).trace_count for n, s in sims.items()}
+    seq0 = BUS.seq
+    again = run_sweep(bf, spec, **kw)
+    assert BUS.seq == seq0                  # no sink: no event
+    assert {n: runner_for(s).trace_count for n, s in sims.items()} == tc0
+    assert again == rows
+
+    with capture() as sink:
+        run_sweep(bf, spec, **kw)
+    params = [e for e in sink.of("transfer") if e["what"] == "params"]
+    assert [e["lanes"] for e in params] == [3, 2]
+    for e, n in zip(params, (2, 3)):
+        leaves = jax.tree.leaves(sims[n].default_params())
+        assert e["bytes"] == e["lanes"] * sum(x.nbytes for x in leaves)
+        assert e["dur"] >= 0.0
+    assert {n: runner_for(s).trace_count for n, s in sims.items()} == tc0
